@@ -19,7 +19,7 @@ for name in ("image_decode", "shm_arena"):
 PY
 
 echo "== test suite (8-device virtual CPU mesh; see tests/conftest.py) =="
-# COV=1 ./ci.sh adds line coverage; the figure is recorded in RESULTS.md.
+# COV=1 ./ci.sh adds line coverage.
 # Uses pytest-cov when installed, else the stdlib sys.monitoring collector
 # (tools/run_coverage.py - coverage.py is uninstallable in the zero-egress
 # build env). Runs inside docker/Dockerfile, which pins this toolchain

@@ -1,7 +1,7 @@
 """Closed-loop pipeline autotuning: runtime-adaptive workers, queue bounds
 and prefetch depth, driven by the live metrics sampler.
 
-Every headline number in RESULTS.md was hand-tuned per host (worker count
+Every headline number of the early rounds was hand-tuned per host (worker count
 peaks at 2 on a 1-core box and degrades past it; the stall win needed
 ``-w 1 --prefetch 3``), which means static defaults leave throughput on the
 table on any other host shape.  tf.data solves the same problem with a
@@ -80,7 +80,7 @@ class AutotunePolicy:
 
     The defaults are deliberately conservative (seconds-scale settle and
     evaluation windows): a decision judged on too few sampler points would
-    chase host noise - RESULTS.md documents +-30% drift on the reference
+    chase host noise - +-30% drift between sessions on the reference
     box - and the revert machinery only protects against moves it can
     measure.  Tests and benchmarks shrink the windows for speed.
     """

@@ -88,7 +88,7 @@ class CircuitOpenError(OSError, PetastormTpuError):
     plainly down (a retry storm compounds an outage: N workers x
     max_attempts x backoff of traffic against a struggling backend).
 
-    Subclasses ``OSError`` so the existing failure taxonomy holds: the
+    Subclasses ``OSError`` so the existing failure classification holds: the
     exhausted-retry classification (``classify_error`` -> ``'data'``)
     applies, meaning an ``on_error`` skip policy quarantines the affected
     rowgroups and a budgeted policy trips

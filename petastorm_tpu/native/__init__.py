@@ -40,7 +40,7 @@ def _load_lib() -> Optional[ctypes.CDLL]:
 
 def is_available() -> bool:
     """True if the zero-copy transport plane works here: the native library
-    builds AND the interpreter supports PEP 688 buffer-protocol leases."""
+    builds."""
     return transport_availability()["available"]
 
 
@@ -48,17 +48,8 @@ def transport_availability() -> dict:
     """``{"available": bool, "reason": str}`` for the zero-copy transport
     plane - the *why* behind :func:`is_available`, surfaced in
     ``Reader.diagnostics['native']['shm_transport']`` and the service
-    client's hello log so a silently dark fast path (e.g. python < 3.12)
-    is observable instead of just slow."""
-    import sys
-
-    if sys.version_info < (3, 12):
-        # zero-copy leases rely on the PEP 688 buffer protocol (__buffer__),
-        # which np.frombuffer only honors from 3.12
-        return {"available": False,
-                "reason": f"python {sys.version_info.major}."
-                          f"{sys.version_info.minor} < 3.12 (zero-copy"
-                          " leases need the PEP 688 buffer protocol)"}
+    client's hello log so a silently dark fast path is observable instead
+    of just slow."""
     if not allocator_available():
         return {"available": False,
                 "reason": "native shm_arena library unavailable (no"
@@ -67,9 +58,7 @@ def transport_availability() -> dict:
 
 
 def allocator_available() -> bool:
-    """True if the C arena allocator itself is usable (no interpreter-version
-    gate: copy-based users like the shared warm-cache tier work on any
-    python - only the transport's zero-copy leases need 3.12)."""
+    """True if the C arena allocator itself is usable."""
     return _load_lib() is not None
 
 

@@ -48,16 +48,22 @@ def _idct_basis() -> np.ndarray:
 def _idct_blocks(coefs: jax.Array, qtab: jax.Array) -> jax.Array:
     """(..., bh, bw, 64) int16 coefs + (..., 64) qtab -> (..., bh*8, bw*8) f32.
 
-    Level-shifted (+128) spatial samples, unclipped.
+    Level-shifted (+128) spatial samples, clamped to [0, 255] as libjpeg's
+    range limit clamps every component sample before upsampling and colour
+    conversion (without it, decoded pixels of busy images drifted up to 12
+    levels from libjpeg's).
     """
     *lead, bh, bw, _ = coefs.shape
     x = coefs.astype(jnp.float32) * qtab.astype(jnp.float32)[..., None, None, :]
     x = x.reshape(*lead, bh, bw, 8, 8)
     a = jnp.asarray(_idct_basis())
-    # spatial[k, l] = sum_uv X[u, v] A[u, k] A[v, l]
+    # spatial[k, l] = sum_uv X[u, v] A[u, k] A[v, l].  HIGHEST: a TPU's
+    # default precision rounds the dequantized coefficients (up to ~2^10)
+    # to bf16, which moved decoded pixels by up to 8 levels on a v5e
     s = jnp.einsum("...uv,uk,vl->...kl", x, a, a,
+                   precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)
-    s = s + 128.0
+    s = jnp.clip(s + 128.0, 0.0, 255.0)
     # (..., bh, bw, 8, 8) -> (..., bh, 8, bw, 8) -> (..., bh*8, bw*8)
     s = jnp.moveaxis(s, -2, -3)
     return s.reshape(*lead, bh * 8, bw * 8)
@@ -143,7 +149,8 @@ def decode_coefficients(planes: Sequence[jax.Array],
     else:
         ycc = jnp.stack(comps, axis=-1)  # (N, H, W, 3)
         ycc = ycc - jnp.asarray([0.0, 128.0, 128.0], dtype=jnp.float32)
-        out = ycc @ jnp.asarray(_YCC_TO_RGB).T
+        out = jnp.matmul(ycc, jnp.asarray(_YCC_TO_RGB).T,
+                         precision=jax.lax.Precision.HIGHEST)
     if jnp.issubdtype(jnp.dtype(out_dtype), jnp.integer):
         out = jnp.clip(jnp.round(out), 0, 255)
     return out.astype(out_dtype)

@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from petastorm_tpu.ops._compat import shard_map as _shard_map
 
 
 def _merge(o, l, m, o_new, l_new, m_new):
@@ -133,8 +132,8 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "seq",
     spec = P(batch_axes, None, seq_axis, None)
     inner = functools.partial(ring_attention_sharded, axis_name=seq_axis,
                               causal=causal, scale=scale)
-    fn = _shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec)
     sharding = NamedSharding(mesh, spec)
     q, k, v = (jax.lax.with_sharding_constraint(x, sharding) for x in (q, k, v))
     return fn(q, k, v)

@@ -767,7 +767,7 @@ class ThreadedExecutor(ExecutorBase):
                          item_deadline_s=item_deadline_s,
                          hedge_after_s=hedge_after_s)
         self._workers_count = workers_count
-        # Queue choice is correctness-driven (hang post-mortem, RESULTS.md):
+        # Queue choice is correctness-driven (round-5 hang post-mortem):
         # CPython's SimpleQueue.get(timeout) WEDGES under multiple
         # concurrent consumers — when a waiter wins the internal lock but a
         # sibling steals the item before it reacquires the GIL, the
@@ -819,7 +819,7 @@ class ThreadedExecutor(ExecutorBase):
         # per-worker heartbeat: [ordinal-or-None, monotonic-since].  Written
         # only by the owning worker (single-writer per slot, no lock needed);
         # read by diagnostics to attribute a pipeline stall to the exact
-        # worker and work item (RESULTS.md hang watch item).
+        # worker and work item.
         self._worker_state: list = []
         # fault servicing (consumer-thread-only state): worker indexes whose
         # death has been handled

@@ -705,67 +705,3 @@ def test_loader_prefetch_knob_attaches_and_resizes(tmp_path):
             assert r.autotune.knobs()["prefetch"] == 5
             n = sum(int(next(iter(b.values())).shape[0]) for b in loader)
     assert n == 64
-
-
-# -- bench_compare weather gating (satellite) ---------------------------------
-
-def test_bench_compare_weather_flag_skips_gate(tmp_path):
-    import json
-
-    from tools import bench_compare
-
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(
-        {"metric": "imagenet_ingest_samples_per_sec", "value": 100.0}) + "\n")
-    # candidate regressed 50% but is weather-flagged: gate must SKIP it
-    new.write_text(json.dumps(
-        {"metric": "imagenet_ingest_samples_per_sec", "value": 50.0,
-         "weather": "degraded"}) + "\n")
-    assert bench_compare.main([str(old), str(new),
-                               "--fail-threshold", "10"]) == 0
-    # the same regression without the flag still fails the gate
-    new.write_text(json.dumps(
-        {"metric": "imagenet_ingest_samples_per_sec", "value": 50.0}) + "\n")
-    assert bench_compare.main([str(old), str(new),
-                               "--fail-threshold", "10"]) == 1
-
-
-def test_bench_compare_summary_weather_list(tmp_path, capsys):
-    import json
-
-    from tools import bench_compare
-
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps({"a": 100.0, "b": 100.0}))
-    new.write_text(json.dumps(
-        {"metric": "bench_summary", "metrics": {"a": [40.0, 0.4],
-                                                "b": [95.0, 0.95]},
-         "weather_degraded": ["a"]}) + "\n")
-    assert bench_compare.main([str(old), str(new), "--json",
-                               "--fail-threshold", "10"]) == 0
-    out = json.loads(capsys.readouterr().out)
-    assert out["weather_skipped"] == ["a"]
-    assert out["failures"] == []
-
-
-def test_bench_child_weather_scan(monkeypatch):
-    """Adaptive-commit disablement warnings from train SUBPROCESSES (the
-    device-path loaders run in children with captured stderr, so the
-    parent-side logging handler never sees them) must still flip the weather
-    verdict once >= 2 accumulate."""
-    monkeypatch.setenv("_PST_BENCH_CHILD", "1")  # suppress the re-exec guard
-    import bench
-
-    monkeypatch.setitem(bench._WEATHER, "commit_disables", 0)
-    monkeypatch.setitem(bench._WEATHER, "status", "ok")
-    bench._scan_child_weather(
-        "step 3: slow dispatch; disabling per-batch commit\n"
-        "step 9: slow dispatch; disabling per-batch commit\n")
-    assert bench._WEATHER["commit_disables"] == 2
-    assert bench._tunnel_weather() == "degraded"
-    # a single warning is not enough: the healthy probe verdict stands
-    monkeypatch.setitem(bench._WEATHER, "commit_disables", 0)
-    bench._scan_child_weather("disabling per-batch commit\n")
-    assert bench._tunnel_weather() == "ok"

@@ -116,7 +116,7 @@ def test_state_dict_requires_real_reader():
 
 
 def test_drain_to_cursor_exact_resume(ds):
-    """VERDICT round-1 #9: drain() + state_dict() is an exact cursor - resume
+    """drain() + state_dict() is an exact cursor - resume
     re-reads ZERO rows, with a thread pool and the HBM device shuffle buffer
     both active."""
     import collections

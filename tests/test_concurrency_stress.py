@@ -1,4 +1,4 @@
-"""Concurrency scaling under oversubscription (VERDICT round-1 weak #5 / next #6).
+"""Concurrency scaling under oversubscription.
 
 This host has few cores, so 8-16 workers here exercise CONTENTION, ordering,
 and leak behavior rather than speedup - exactly the properties that must hold

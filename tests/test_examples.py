@@ -54,11 +54,27 @@ def test_imagenet_resnet_smoke(tmp_path):
     assert m["samples_per_sec"] > 0
     assert 0.0 <= m["device_idle_pct"] <= 100.0
     assert m["diagnostics"]["delivered_batches"] >= m["steps"]
-    # hybrid on-chip decode (the default) feeds the same training step;
-    # train() itself falls back to host decode when the native lib is absent
+    # hybrid on-chip decode (the default) feeds the same training step
     m = train(url, steps=2, global_batch=8, side=64, num_classes=10,
               decode="device")
     assert m["samples_per_sec"] > 0
+
+
+def test_imagenet_device_decode_refuses_host_fallback(tmp_path, monkeypatch):
+    """decode='device' without the native image library is an error, never
+    a silent switch to host decode."""
+    import jax
+    from jax.sharding import Mesh
+
+    from examples.imagenet.train_resnet_tpu import generate_dataset, make_feed
+    from petastorm_tpu.native import image as native_image
+
+    url = str(tmp_path / "imagenet")
+    generate_dataset(url, rows=8, side=32)
+    monkeypatch.setattr(native_image, "available", lambda: False)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    with pytest.raises(RuntimeError, match="native image library"):
+        make_feed(url, mesh, 8, decode="device")
 
 
 def test_long_context_smoke(tmp_path):

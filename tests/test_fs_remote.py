@@ -1,4 +1,4 @@
-"""End-to-end reader over NON-local filesystems (VERDICT round-1 weak #6).
+"""End-to-end reader over NON-local filesystems.
 
 Two schemes drive the fsspec fallback branch (fs.py:85-93) the way a real
 object store would, without network:

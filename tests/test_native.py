@@ -336,9 +336,10 @@ class TestNativeImageDecode:
         calls = []
         orig = native_image.decode_column_native
 
-        def spy(column, out, nthreads=1):
+        def spy(column, out, nthreads=1, **kwargs):
+            # kwargs: the roi=/full_shape= crop arguments the codec passes
             calls.append(len(column))
-            return orig(column, out, nthreads=nthreads)
+            return orig(column, out, nthreads=nthreads, **kwargs)
 
         monkeypatch.setattr(native_image, "decode_column_native", spy)
         codec = CompressedImageCodec("png")

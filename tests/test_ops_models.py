@@ -51,6 +51,46 @@ def test_normalize_pallas_kernel_interpret_matches():
     np.testing.assert_allclose(np.asarray(out), want, atol=1e-5)
 
 
+@pytest.mark.parametrize("mesh_shape,axes,batch_axis", [
+    ((8,), ("data",), "data"),                    # the loader's default axis
+    ((4, 2), ("data", "model"), "data"),          # rows replicated over model
+    ((2, 4), ("model", "data"), "data"),          # batch not on the first axis
+])
+def test_normalize_pallas_sharded_matches_xla(monkeypatch, mesh_shape, axes,
+                                              batch_axis):
+    """The kernel path over a sharded batch (shard_map, one block of rows per
+    device) gives the XLA expression's values, with the rows staying put;
+    the kernel runs in interpret mode so the CPU can take the TPU branch.
+    A batch that is not on the first mesh axis is resharded around the
+    kernel, with the same result."""
+    from functools import partial
+
+    from jax.experimental import pallas as pl
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from petastorm_tpu.ops import normalize as nz
+
+    monkeypatch.setattr(pl, "pallas_call",
+                        partial(pl.pallas_call, interpret=True))
+    mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(mesh_shape), axes)
+    batch = P(batch_axis)
+    rng = np.random.default_rng(2)
+    imgs = jax.device_put(rng.integers(0, 255, (64, 8, 16, 3), dtype=np.uint8),
+                          NamedSharding(mesh, batch))
+    length = 8 * 16 * 3
+    scale = jnp.asarray(rng.random((1, length), np.float32))
+    bias = jnp.asarray(rng.random((1, length), np.float32))
+    out = nz._normalize_impl(imgs, scale, bias, jnp.float32, True)
+    want = nz._normalize_impl(imgs, scale, bias, jnp.float32, False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
+    if batch_axis == axes[0]:
+        assert out.sharding.is_equivalent_to(NamedSharding(mesh, batch), 4)
+    lowered = jax.jit(partial(nz._normalize_impl, out_dtype=jnp.float32,
+                              use_pallas=True)
+                      ).lower(imgs, scale, bias).as_text()
+    assert "shard_map" in lowered or "sdy.manual_computation" in lowered
+
+
 def test_normalize_rejects_bad_inputs():
     with pytest.raises(TypeError):
         normalize_images(jnp.zeros((2, 4, 4, 3), jnp.float32))

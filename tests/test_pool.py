@@ -98,8 +98,8 @@ def test_diagnostics():
 
 def test_workers_busy_heartbeat_names_stuck_item():
     """A wedged worker is attributable: diagnostics report (worker index,
-    item ordinal, seconds stuck) while it is inside fn (RESULTS.md hang
-    watch item -> stall diagnostics)."""
+    item ordinal, seconds stuck) while it is inside fn (stall
+    diagnostics)."""
     import threading
 
     from petastorm_tpu.pool import VentilatedItem

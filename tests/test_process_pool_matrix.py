@@ -1,4 +1,4 @@
-"""Feature matrix under the PROCESS pool + shm transport (VERDICT r2 item 8).
+"""Feature matrix under the PROCESS pool + shm transport.
 
 The reference runs its full behavior matrix across every pool flavor
 (tests/test_end_to_end.py:44-59).  Spawn costs ~1-3 s/worker on the 1-core CI

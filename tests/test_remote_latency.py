@@ -1,4 +1,4 @@
-"""Remote IO under injected latency (VERDICT r4 item 4).
+"""Remote IO under injected latency.
 
 ``memory://`` and local disk answer in microseconds; real object stores
 charge 10-50 ms per request.  These tests run the production remote code

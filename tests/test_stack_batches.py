@@ -1,6 +1,6 @@
 """stack_batches=K: scan-feed delivery as a first-class loader capability.
 
-VERDICT round-4 item 1: the lax.scan dispatch-amortization win moves from
+The lax.scan dispatch-amortization win moves from
 example code into ``JaxDataLoader`` - ONE ``(K, batch, ...)`` transfer per K
 steps, with drain/resume and the valid-mask contract defined at stack
 granularity.  Reference analog: none (the reference feeds BatchedDataLoader

@@ -192,7 +192,7 @@ def pod_ordered_ds(tmp_path_factory):
 
 
 def test_pod_scale_shuffle_quality(pod_ordered_ds):
-    """VERDICT r3 item 6: shuffle quality AT POD SCALE.  8 simulated shards,
+    """Shuffle quality AT POD SCALE.  8 simulated shards,
     two epochs with different seeds: every shard's own stream AND the
     concatenated global stream must decorrelate from the written order
     (explicit rank-correlation thresholds), shards must partition the dataset

@@ -14,7 +14,7 @@ Usage::
     COV=1 ./ci.sh                               # CI entry
 
 Reference analog: the reference tracks line coverage via codecov
-(/root/reference/README.rst:4-12); the recorded figure lives in RESULTS.md.
+(/root/reference/README.rst:4-12).
 
 Caveats (stated in the report): subprocess children (spawn-based process
 pools, the multi-process selfcheck workers, bench train children) execute

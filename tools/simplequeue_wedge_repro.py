@@ -2,12 +2,12 @@
 """Standalone reproduction of the CPython SimpleQueue timed-get wedge.
 
 This is the minimal form of the bug that froze a full test-suite run
-(RESULTS.md round-5 post-mortem) and motivated moving ThreadedExecutor's
+(round-5 post-mortem) and motivated moving ThreadedExecutor's
 input queue from ``queue.SimpleQueue`` to ``queue.Queue``
 (petastorm_tpu/pool.py).  Pure stdlib, no petastorm_tpu imports.
 
 Mechanism (confirmed by disassembling the installed CPython 3.12.12
-``_queue`` extension — see RESULTS.md for the control-flow walkthrough):
+``_queue`` extension):
 
 ``SimpleQueue.get(timeout=t)`` waits by acquiring an internal lock that
 ``put`` releases.  When a waiter's blocking acquire SUCCEEDS (a put

@@ -3,7 +3,7 @@
 A progress-based wedge watchdog whose dumps carry BOTH every thread's
 Python stack and each OS thread's in-flight syscall + kernel wait channel
 (/proc/self/task) — the evidence set that root-caused the round-5
-SimpleQueue wedge (RESULTS.md) — plus a validated dataset cache so a
+SimpleQueue wedge — plus a validated dataset cache so a
 killed first run can never turn later runs into non-reproducible
 invariant failures.
 """

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Thread-pool wedge soak: hammer the oversubscribed reader until it wedges.
 
-Post-mortem tool for the RESULTS.md hang watch item (a full-suite run froze
+Post-mortem tool for the round-5 hang (a full-suite run froze
 with one worker stuck inside a timed queue get while ``join()`` waited on it
 forever).  Runs the oversubscribed stress-test loop continuously with a
 PROGRESS-based watchdog: wall-clock slowness from competing load never
