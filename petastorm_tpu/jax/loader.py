@@ -230,12 +230,14 @@ class JaxDataLoader:
         #: host-queue depth gauge: with the prefetch-depth gauge (set in
         #: __next__) the metrics sampler sees both producer stages' backlogs
         self._g_host_depth = self._telemetry.gauge("loader.host_queue_depth")
+        self._g_prefetch_depth = self._telemetry.gauge("loader.prefetch_depth")
         if self._telemetry.enabled:
             register = getattr(self._telemetry, "register_stage", None)
             if register is not None:
-                # visible as "no samples yet" before the first batch lands
+                # visible as "no samples yet" before the first batch lands;
+                # 'commit' stays at zero while commits are off
                 for stage in ("host-assemble", "host-prep",
-                              "device-transfer"):
+                              "device-transfer", "commit"):
                     register(stage)
         self._mesh = mesh
         self._specs = shardings
@@ -478,6 +480,10 @@ class JaxDataLoader:
         self._finished = False
         self._failure: Optional[BaseException] = None
         self._delivered_batches = 0
+        #: sequence number of the next batch the transfer thread emits: the
+        #: ``batch`` id its spans carry (the delivered batch's number, unless
+        #: device_shuffle_capacity reorders delivery)
+        self._emit_seq = 0
         #: producer threads that failed to quiesce within the stop() join
         #: budget ([{thread, stage}]); surfaced in diagnostics so a silent
         #: shutdown wedge is visible post-mortem, not swallowed
@@ -774,7 +780,8 @@ class JaxDataLoader:
                     # field selection) is a distinct stage from 'host-prep'
                     # (per DELIVERED batch in _emit) - one shared name would
                     # mix two granularities and corrupt count/mean/p50
-                    with (tele.stage("host-assemble", rows=raw.num_rows)
+                    with (tele.stage("host-assemble", rows=raw.num_rows,
+                                     ordinal=raw.ordinal)
                           if tele.enabled else _NULL_CONTEXT):
                         out = self._prepare(raw)
                     yield out
@@ -921,7 +928,9 @@ class JaxDataLoader:
     def _emit(self, host_batch: ColumnBatch) -> None:
         tele = self._telemetry
         traced = tele.enabled
-        with (tele.stage("host-prep", rows=host_batch.num_rows)
+        seq = self._emit_seq
+        self._emit_seq += 1
+        with (tele.stage("host-prep", rows=host_batch.num_rows, batch=seq)
               if traced else _NULL_CONTEXT):
             cols, valid_rows = self._prep_cols(
                 host_batch,
@@ -930,7 +939,8 @@ class JaxDataLoader:
                 mask = np.zeros(self._local_rows, np.float32)
                 mask[:valid_rows] = 1.0
                 cols[self._valid_mask] = mask
-        transfer_stage = (tele.stage("device-transfer", rows=valid_rows)
+        transfer_stage = (tele.stage("device-transfer", rows=valid_rows,
+                                     batch=seq)
                           if traced else _NULL_CONTEXT)
         with transfer_stage:
             device_batch = {}
@@ -943,27 +953,34 @@ class JaxDataLoader:
                     decode = (self._decode_mixed_on_device
                               if name in self._mixed_decode
                               else self._decode_on_device)
-                    device_batch[name] = decode(name, host_batch.columns)
-            staged: Dict[str, np.ndarray] = {}
-            for name, col in cols.items():
-                arr = np.ascontiguousarray(col)
-                feed_dtype = jax_feed_dtype(arr.dtype, keep_wide=self._keep_wide)
-                if arr.dtype != feed_dtype:
-                    arr = arr.astype(feed_dtype)
-                self._emitted_layout[name] = (arr.shape[1:], arr.dtype)
-                if self._mesh is not None:
-                    sharding, sl, global_shape = self._placement_for(name, arr.shape[1:])
-                    arr = arr[(slice(None),) + sl[1:]]  # sequence/model-axis slice
-                    device_batch[name] = jax.make_array_from_process_local_data(
-                        sharding, arr, global_shape)
-                else:
-                    staged[name] = arr
-            if staged:
-                # ONE device_put for all fields: each call pays a fixed dispatch
-                # cost, so a small label column must not cost as much as the
-                # image column it rides with
-                device_batch.update(jax.device_put(staged))
-            self._commit(device_batch)
+                    with (tele.span("device-decode", batch=seq)
+                          if traced else _NULL_CONTEXT):
+                        device_batch[name] = decode(name, host_batch.columns)
+            with tele.span("put", batch=seq) if traced else _NULL_CONTEXT:
+                staged: Dict[str, np.ndarray] = {}
+                for name, col in cols.items():
+                    arr = np.ascontiguousarray(col)
+                    feed_dtype = jax_feed_dtype(arr.dtype,
+                                                keep_wide=self._keep_wide)
+                    if arr.dtype != feed_dtype:
+                        arr = arr.astype(feed_dtype)
+                    self._emitted_layout[name] = (arr.shape[1:], arr.dtype)
+                    if self._mesh is not None:
+                        sharding, sl, global_shape = self._placement_for(
+                            name, arr.shape[1:])
+                        # sequence/model-axis slice
+                        arr = arr[(slice(None),) + sl[1:]]
+                        device_batch[name] = \
+                            jax.make_array_from_process_local_data(
+                                sharding, arr, global_shape)
+                    else:
+                        staged[name] = arr
+                if staged:
+                    # ONE device_put for all fields: each call pays a fixed
+                    # dispatch cost, so a small label column must not cost
+                    # as much as the image column it rides with
+                    device_batch.update(jax.device_put(staged))
+            self._commit(device_batch, seq)
         for name in self._host_fields:
             device_batch[name] = host_batch.columns[name]
         if self._mesh is not None and valid_rows < self._local_rows:
@@ -998,8 +1015,10 @@ class JaxDataLoader:
         real_steps = len(group)
         tele = self._telemetry
         traced = tele.enabled
+        seq = self._emit_seq
+        self._emit_seq += 1
         prepped, valids = [], []
-        with (tele.stage("host-prep", steps=real_steps)
+        with (tele.stage("host-prep", steps=real_steps, batch=seq)
               if traced else _NULL_CONTEXT):
             for hb in group:
                 # pad even without a mesh: the (K, B, ...) stack needs one
@@ -1008,7 +1027,8 @@ class JaxDataLoader:
                 prepped.append(cols)
                 valids.append(valid)
 
-        transfer_stage = (tele.stage("device-transfer", steps=real_steps)
+        transfer_stage = (tele.stage("device-transfer", steps=real_steps,
+                                     batch=seq)
                           if traced else _NULL_CONTEXT)
         with transfer_stage:
             device_batch = {}
@@ -1017,39 +1037,45 @@ class JaxDataLoader:
                     decode = (self._decode_mixed_stack
                               if name in self._mixed_decode
                               else self._decode_stack)
-                    device_batch[name] = decode(name, group)
+                    with (tele.span("device-decode", batch=seq)
+                          if traced else _NULL_CONTEXT):
+                        device_batch[name] = decode(name, group)
 
-            staged: Dict[str, np.ndarray] = {}
-            for name in (list(prepped[0]) if prepped else []):
-                steps = [np.ascontiguousarray(p[name]) for p in prepped]
-                steps += [np.zeros_like(steps[-1])] * (K - real_steps)
-                arr = np.stack(steps)                      # (K, local, *trailing)
-                feed_dtype = jax_feed_dtype(arr.dtype, keep_wide=self._keep_wide)
-                if arr.dtype != feed_dtype:
-                    arr = arr.astype(feed_dtype)
-                self._emitted_layout[name] = (arr.shape[2:], arr.dtype)
-                if self._mesh is not None:
-                    sharding, sl, global_shape = self._placement_for(
-                        name, arr.shape[2:])
-                    arr = arr[(slice(None), slice(None)) + sl[2:]]
+            with tele.span("put", batch=seq) if traced else _NULL_CONTEXT:
+                staged: Dict[str, np.ndarray] = {}
+                for name in (list(prepped[0]) if prepped else []):
+                    steps = [np.ascontiguousarray(p[name]) for p in prepped]
+                    steps += [np.zeros_like(steps[-1])] * (K - real_steps)
+                    arr = np.stack(steps)              # (K, local, *trailing)
+                    feed_dtype = jax_feed_dtype(arr.dtype,
+                                                keep_wide=self._keep_wide)
+                    if arr.dtype != feed_dtype:
+                        arr = arr.astype(feed_dtype)
+                    self._emitted_layout[name] = (arr.shape[2:], arr.dtype)
+                    if self._mesh is not None:
+                        sharding, sl, global_shape = self._placement_for(
+                            name, arr.shape[2:])
+                        arr = arr[(slice(None), slice(None)) + sl[2:]]
+                        device_batch[name] = \
+                            jax.make_array_from_process_local_data(
+                                sharding, arr, global_shape)
+                    else:
+                        staged[name] = arr
+                if self._valid_mask is not None:
+                    mask = np.zeros((K, local), np.float32)
+                    for k, v in enumerate(valids):
+                        mask[k, :v] = 1.0
+                    name = self._valid_mask
+                    self._emitted_layout[name] = ((), np.dtype(np.float32))
+                    sharding, _, global_shape = self._placement_for(name, ())
                     device_batch[name] = jax.make_array_from_process_local_data(
-                        sharding, arr, global_shape)
-                else:
-                    staged[name] = arr
-            if self._valid_mask is not None:
-                mask = np.zeros((K, local), np.float32)
-                for k, v in enumerate(valids):
-                    mask[k, :v] = 1.0
-                name = self._valid_mask
-                self._emitted_layout[name] = ((), np.dtype(np.float32))
-                sharding, _, global_shape = self._placement_for(name, ())
-                device_batch[name] = jax.make_array_from_process_local_data(
-                    sharding, mask, global_shape)
-            if staged:
-                # ONE device_put for the whole stack: K steps of data ride a
-                # single fixed-cost dispatch instead of K (the whole point)
-                device_batch.update(jax.device_put(staged))
-            self._commit(device_batch)
+                        sharding, mask, global_shape)
+                if staged:
+                    # ONE device_put for the whole stack: K steps of data
+                    # ride a single fixed-cost dispatch instead of K (the
+                    # whole point)
+                    device_batch.update(jax.device_put(staged))
+            self._commit(device_batch, seq)
         for name in self._host_fields:
             steps = [_pad_host_col(hb.columns[name], local) for hb in group]
             steps += [_host_filler(steps[-1])] * (K - real_steps)
@@ -1059,7 +1085,7 @@ class JaxDataLoader:
                 valids + [0] * (K - real_steps), dtype=np.int64)
         self._push(device_batch)
 
-    def _commit(self, device_batch) -> None:
+    def _commit(self, device_batch, seq: int) -> None:
         """Commit the transfers in the transfer thread: the consumer then
         never blocks on a half-copied array, and its readiness query never
         queues behind the next batch's dispatch (serialized device RPC
@@ -1084,8 +1110,13 @@ class JaxDataLoader:
             self._probe_commit_cost()
         if not self._commit_transfers:
             return
+        tele = self._telemetry
         t0 = time.perf_counter()
-        jax.block_until_ready(device_batch)
+        # the 'commit' stage: the transfer thread waiting on the batch's
+        # arrays, the on-chip decode finish included
+        with (tele.stage("commit", batch=seq) if tele.enabled
+              else _NULL_CONTEXT):
+            jax.block_until_ready(device_batch)
         took = time.perf_counter() - t0
         self._commit_count += 1
         if self._commit_count == 1:
@@ -1570,31 +1601,11 @@ class JaxDataLoader:
         if not self._started:
             iter(self)
         wait_start = time.perf_counter()
-        while True:
-            try:
-                value = self._out.get(timeout=_QUEUE_POLL_S)
-                waited = time.perf_counter() - wait_start
-                self._consumer_wait_s += waited
-                if self._telemetry.enabled:
-                    self._m_consumer_wait.add(waited)
-                    self._telemetry.gauge("loader.prefetch_depth").set(
-                        self._out.qsize())
-                break
-            except queue.Empty:
-                if self._stop_event.is_set():
-                    self._finished = True
-                    raise StopIteration
-                if not self._transfer_thread.is_alive():
-                    # the producer may have pushed its sentinel between our
-                    # timeout and this liveness check - drain before concluding
-                    try:
-                        value = self._out.get_nowait()
-                        break
-                    except queue.Empty:
-                        self._failure = PetastormTpuError(
-                            "Loader producer thread died silently")
-                        self._stop_trace()
-                        raise self._failure
+        tele = self._telemetry
+        # 'consumer-wait' spans only a get that finds the queue empty
+        with (tele.span("consumer-wait", batch=self._delivered_batches)
+              if tele.enabled and self._out.empty() else _NULL_CONTEXT):
+            value = self._next_value(wait_start)
         if isinstance(value, _Done):
             self._finished = True
             self._sentinel_pending = False
@@ -1608,6 +1619,33 @@ class JaxDataLoader:
         self._delivered_batches += 1
         self._m_delivered.add(1)
         return value
+
+    def _next_value(self, wait_start: float):
+        """Block for the consumer queue's next value (a batch or a
+        sentinel), accounting the wait as consumer wait time."""
+        while True:
+            try:
+                value = self._out.get(timeout=_QUEUE_POLL_S)
+                waited = time.perf_counter() - wait_start
+                self._consumer_wait_s += waited
+                if self._telemetry.enabled:
+                    self._m_consumer_wait.add(waited)
+                    self._g_prefetch_depth.set(self._out.qsize())
+                return value
+            except queue.Empty:
+                if self._stop_event.is_set():
+                    self._finished = True
+                    raise StopIteration
+                if not self._transfer_thread.is_alive():
+                    # the producer may have pushed its sentinel between our
+                    # timeout and this liveness check - drain before concluding
+                    try:
+                        return self._out.get_nowait()
+                    except queue.Empty:
+                        self._failure = PetastormTpuError(
+                            "Loader producer thread died silently")
+                        self._stop_trace()
+                        raise self._failure
 
     # -- checkpoint/resume (reference gap: SURVEY.md section 5) ---------------
 

@@ -1033,15 +1033,33 @@ class ThreadedExecutor(ExecutorBase):
     def _put_result_stop_aware(self, value: Any) -> None:
         # reference _stop_aware_put (thread_pool.py:200-214): bound via the
         # slot semaphore, never block indefinitely across a stop
-        t0 = time.perf_counter() if self._telemetry.enabled else None
+        tele = self._telemetry
+        if not tele.enabled:
+            if self._acquire_result_slot():
+                self._out_queue.put(value)
+            return
+        t0 = time.perf_counter()
+        if self._stop_event.is_set():
+            return
+        if not self._out_slots.acquire(blocking=False):
+            # the slot is not free at once: the wait is a 'results-wait'
+            # span on this worker's timeline
+            with tele.span("results-wait",
+                           ordinal=getattr(value, "ordinal", None)):
+                if not self._acquire_result_slot():
+                    return
+        self._out_queue.put(value)
+        # time this worker spent blocked on a full results queue:
+        # sustained values mean the CONSUMER is the bottleneck
+        self._m_results_full.add(time.perf_counter() - t0)
+
+    def _acquire_result_slot(self) -> bool:
+        """A results-queue slot, polled so a stop is never slept through;
+        False once stopped."""
         while not self._stop_event.is_set():
             if self._out_slots.acquire(timeout=_POLL_S):
-                self._out_queue.put(value)
-                if t0 is not None:
-                    # time this worker spent blocked on a full results queue:
-                    # sustained values mean the CONSUMER is the bottleneck
-                    self._m_results_full.add(time.perf_counter() - t0)
-                return
+                return True
+        return False
 
     def put(self, item: Any, cancel_event=None) -> None:
         if self._stopped:

@@ -52,6 +52,7 @@ from petastorm_tpu.pool import (DEFAULT_REQUEUE_ATTEMPTS, PipelineStallError,
                                 Ventilator, WorkerError, _env_seconds,
                                 make_executor)
 from petastorm_tpu.schema import Schema
+from petastorm_tpu.telemetry import NULL_CONTEXT as _NULL_CONTEXT
 from petastorm_tpu.telemetry import dominant_stage
 from petastorm_tpu.telemetry import resolve as _resolve_telemetry
 from petastorm_tpu.transform import TransformSpec, transform_schema
@@ -1530,7 +1531,11 @@ class Reader:
             # empty results queue (the "worker plane is the bottleneck" signal)
             t0 = time.perf_counter() if tele.enabled else None
             try:
-                batch = self._executor.get(timeout=_GET_TIMEOUT_S)
+                # 'results-empty' spans each get: microseconds long when a
+                # result was ready, the starvation itself when none was
+                with (tele.span("results-empty") if t0 is not None
+                      else _NULL_CONTEXT):
+                    batch = self._executor.get(timeout=_GET_TIMEOUT_S)
             except WorkerError as exc:
                 if t0 is not None:
                     self._m_results_empty.add(time.perf_counter() - t0)

@@ -152,7 +152,16 @@ class SequencePacker:
 
     def feed(self, doc) -> List[Dict[str, np.ndarray]]:
         """Pack one document; returns the rows this document completed
-        (usually none or one - more when a long document splits)."""
+        (usually none or one - more when a long document splits).  With
+        telemetry on, each call is a ``pack`` stage whose ``row`` is the
+        number of the next row to be emitted: the packer's own work, the
+        wait for the next document falling between the spans."""
+        if self._telemetry.enabled:
+            with self._telemetry.stage("pack", row=self._rows):
+                return self._feed(doc)
+        return self._feed(doc)
+
+    def _feed(self, doc) -> List[Dict[str, np.ndarray]]:
         if self._finished:
             raise PetastormTpuError("SequencePacker.feed after finish()")
         if doc is None:

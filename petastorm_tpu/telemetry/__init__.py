@@ -29,7 +29,14 @@ Instrumentation contract used across the repo::
 Stage timers feed three sinks at once: a ``stage.<name>.busy_s`` counter and
 ``stage.<name>.count`` (the pipeline report's utilization math), a
 ``stage.<name>.latency_s`` histogram (tail latency), and a trace span (the
-Chrome timeline).  Process pools: the parent instruments ventilation and
+Chrome timeline).  While a ``jax.profiler`` session runs (``start_trace``,
+``JaxDataLoader(trace_dir=...)``), stage timers and ``span()`` also open a
+profiler annotation named ``petastorm/<name>`` carrying the span's numeric
+id arguments, entered and left on the thread that does the work: the span
+then sits on that thread's line of the profile's host plane, on the clock
+the device's operations are on.  The Chrome export stays on the host clock.
+This package never imports jax: the annotation class is looked up only once
+jax is already imported.  Process pools: the parent instruments ventilation and
 queue waits; worker-side stage metrics recorded inside spawned worker
 processes stay in those processes (the env var is inherited, so they record
 independently) - use the thread pool when one merged report matters.
@@ -37,7 +44,9 @@ independently) - use the thread pool when one merged report matters.
 
 from __future__ import annotations
 
+import numbers
 import os
+import sys
 import threading
 import time
 from typing import Dict, Optional, Sequence
@@ -86,12 +95,48 @@ def __getattr__(name: str):
 #: setting this to 1/true/yes/on enables the process-default recorder
 ENV_VAR = "PETASTORM_TPU_TELEMETRY"
 
+#: prefix of the profiler annotations the stage and span timers open
+PROFILER_PREFIX = "petastorm/"
+
+#: jax.profiler.TraceAnnotation once resolved; None = not yet (jax not
+#: imported), False = this jax has none
+_annotation_cls = None
+
+
+def _profiler_mark(name: str, args: Optional[Dict]):
+    """An entered ``petastorm/<name>`` profiler annotation when a profiler
+    session is running, else None.  Only numeric arguments ride along: the
+    annotation encodes its arguments into its name, where a path's commas
+    or ``#`` would corrupt the others."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None  # importing jax here would tax every jax-free user
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except (ImportError, AttributeError):
+            cls = False
+        _annotation_cls = cls
+    if cls is False or not cls.is_enabled():
+        return None
+    ids = {}
+    for k, v in (args or {}).items():
+        if isinstance(v, numbers.Integral):
+            ids[k] = int(v)
+        elif isinstance(v, numbers.Real):
+            ids[k] = float(v)
+    mark = cls(PROFILER_PREFIX + name, **ids)
+    mark.__enter__()
+    return mark
+
 
 class _StageTimer:
     """Context manager recording one stage execution into counters, the
-    latency histogram and the trace buffer (see module docstring)."""
+    latency histogram and the trace buffer, and into a running profiler
+    session (see module docstring)."""
 
-    __slots__ = ("_tele", "_name", "_args", "_t0")
+    __slots__ = ("_tele", "_name", "_args", "_t0", "_mark")
 
     def __init__(self, tele: "Telemetry", name: str, args: Optional[Dict]):
         self._tele = tele
@@ -99,20 +144,24 @@ class _StageTimer:
         self._args = args
 
     def __enter__(self):
+        self._mark = _profiler_mark(self._name, self._args)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t0 = self._t0
         dur_ns = time.perf_counter_ns() - t0
+        if self._mark is not None:
+            self._mark.__exit__(*exc)
         self._tele._record_stage(self._name, t0, dur_ns, self._args)
         return False
 
 
 class _SpanTimer:
-    """Context manager recording one trace span (no stage counters)."""
+    """Context manager recording one trace span (no stage counters), also
+    into a running profiler session."""
 
-    __slots__ = ("_tele", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tele", "_name", "_cat", "_args", "_t0", "_mark")
 
     def __init__(self, tele: "Telemetry", name: str, cat: str,
                  args: Optional[Dict]):
@@ -122,13 +171,16 @@ class _SpanTimer:
         self._args = args
 
     def __enter__(self):
+        self._mark = _profiler_mark(self._name, self._args)
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t0 = self._t0
-        self._tele.trace.add(self._name, self._cat, t0,
-                             time.perf_counter_ns() - t0, self._args)
+        dur_ns = time.perf_counter_ns() - t0
+        if self._mark is not None:
+            self._mark.__exit__(*exc)
+        self._tele.trace.add(self._name, self._cat, t0, dur_ns, self._args)
         return False
 
 
@@ -196,7 +248,8 @@ class Telemetry:
         """Record one stage execution with an explicit duration - for callers
         that must adjust the measured time (e.g. the ventilator subtracts
         queue-full wait so a blocked ``put`` is not mistaken for busy work);
-        prefer ``stage()`` everywhere else."""
+        prefer ``stage()`` everywhere else.  Such a stage stays on the host
+        clock only: it never reaches a running profiler session."""
         self._record_stage(name, start_ns, dur_ns, args)
 
     def _record_stage(self, name: str, t0_ns: int, dur_ns: int,

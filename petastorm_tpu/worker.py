@@ -244,7 +244,7 @@ class RowGroupDecoderWorker:
 
                 stats_before = native_image.decode_stats()
             batch = retry_call(
-                lambda: self._process(_parquet_file, item, fs),
+                lambda: self._process(_parquet_file, item, fs, ordinal),
                 self._retry_policy,
                 what=f"rowgroup {item.row_group.path}"
                      f"#{item.row_group.row_group}",
@@ -280,7 +280,8 @@ class RowGroupDecoderWorker:
 
     # -- hot path -------------------------------------------------------------
 
-    def _process(self, parquet_file, item: WorkItem, fs=None) -> ColumnBatch:
+    def _process(self, parquet_file, item: WorkItem, fs=None,
+                 ordinal: Optional[int] = None) -> ColumnBatch:
         anchor = None
         row_range = None
         if self._ngram is not None:
@@ -304,7 +305,8 @@ class RowGroupDecoderWorker:
         tele = self._telemetry
         traced = tele is not None and tele.enabled
         decode_stage = (tele.stage("decode", path=item.row_group.path,
-                                   rowgroup=item.row_group.row_group)
+                                   rowgroup=item.row_group.row_group,
+                                   ordinal=ordinal)
                         if traced else _NULL_CONTEXT)
         if self._predicate is None:
             # key covers the rows ACTUALLY loaded (incl. ngram lookahead), so
@@ -324,11 +326,13 @@ class RowGroupDecoderWorker:
                 def _decode_and_transform() -> ColumnBatch:
                     filled.append(True)
                     with (tele.stage("decode", path=item.row_group.path,
-                                     rowgroup=item.row_group.row_group)
+                                     rowgroup=item.row_group.row_group,
+                                     ordinal=ordinal)
                           if traced else _NULL_CONTEXT):
                         fresh = self._load(parquet_file, load_item,
                                            self._read_fields,
-                                           row_range=row_range)
+                                           row_range=row_range,
+                                           ordinal=ordinal)
                     if fresh.num_rows == 0:
                         # transforms must not see 0-row columns (same
                         # contract as the uncached path below)
@@ -343,13 +347,13 @@ class RowGroupDecoderWorker:
             with decode_stage:
                 batch = self._cache.get(key, lambda: self._load(
                     parquet_file, load_item, self._read_fields,
-                    row_range=row_range))
+                    row_range=row_range, ordinal=ordinal))
         else:
             # predicates invalidate rowgroup-level caching (reference
             # py_dict_reader_worker.py:145-150); split-read instead
             with decode_stage:
                 batch = self._load_with_predicate(parquet_file, load_item,
-                                                  row_range)
+                                                  row_range, ordinal)
         if batch.num_rows == 0:
             # fully-masked rowgroup: transforms/ngram must not see 0-row columns
             # (a transform may np.stack/reduce over rows)
@@ -480,8 +484,11 @@ class RowGroupDecoderWorker:
 
     def _load(self, parquet_file, item: WorkItem, fields: Sequence[str],
               mask: Optional[np.ndarray] = None,
-              row_range: Optional[tuple] = None) -> ColumnBatch:
-        """Read + slice + (mask) + decode ``fields`` of one rowgroup (no transform)."""
+              row_range: Optional[tuple] = None,
+              ordinal: Optional[int] = None) -> ColumnBatch:
+        """Read + slice + (mask) + decode ``fields`` of one rowgroup (no
+        transform).  With telemetry on, the Parquet read is the ``read``
+        stage, nested in the caller's ``decode``."""
         pf, file_cols, window = parquet_file(item.row_group.path)
         stored = [f for f in fields if f in file_cols]
         virtual = [f for f in fields if f not in file_cols]
@@ -489,19 +496,22 @@ class RowGroupDecoderWorker:
         start, stop = row_range if row_range is not None else item.row_slice()
         tele = self._telemetry
         reads_before = window.raw_reads if window is not None else 0
-        if window is not None and stored:
-            # one ranged read covers the whole rowgroup's needed columns
-            # (io_window): every chunk read below lands in the buffer
-            from petastorm_tpu.io_window import rowgroup_span
+        with (tele.stage("read", ordinal=ordinal,
+                         rowgroup=item.row_group.row_group)
+              if tele is not None and tele.enabled else _NULL_CONTEXT):
+            if window is not None and stored:
+                # one ranged read covers the whole rowgroup's needed columns
+                # (io_window): every chunk read below lands in the buffer
+                from petastorm_tpu.io_window import rowgroup_span
 
-            span = rowgroup_span(pf.metadata, item.row_group.row_group,
-                                 stored)
-            if span is not None:
-                window.prefetch(span[0], span[1])
-        # worker-level parallelism comes from the executor pool; pyarrow's
-        # internal thread fan-out per read only adds handoff overhead here
-        table = pf.read_row_group(item.row_group.row_group, columns=stored,
-                                  use_threads=False)
+                span = rowgroup_span(pf.metadata, item.row_group.row_group,
+                                     stored)
+                if span is not None:
+                    window.prefetch(span[0], span[1])
+            # worker-level parallelism comes from the executor pool; pyarrow's
+            # internal thread fan-out per read only adds handoff overhead here
+            table = pf.read_row_group(item.row_group.row_group,
+                                      columns=stored, use_threads=False)
         if window is not None:
             window.discard_window()  # the decoded table owns the bytes now
             if tele is not None and tele.enabled:
@@ -579,13 +589,15 @@ class RowGroupDecoderWorker:
         return ColumnBatch(cols, 0)
 
     def _load_with_predicate(self, parquet_file, item: WorkItem,
-                             row_range: Optional[tuple] = None) -> ColumnBatch:
+                             row_range: Optional[tuple] = None,
+                             ordinal: Optional[int] = None) -> ColumnBatch:
         pred_fields = list(self._predicate.get_fields())
         missing = [f for f in pred_fields if f not in self._schema]
         if missing:
             raise PetastormTpuError(f"Predicate references unknown fields {missing}")
         # phase 1: predicate columns only (cheap)
-        pred_batch = self._load(parquet_file, item, pred_fields, row_range=row_range)
+        pred_batch = self._load(parquet_file, item, pred_fields,
+                                row_range=row_range, ordinal=ordinal)
         mask = np.asarray(self._predicate.do_include_vectorized(pred_batch.columns),
                           dtype=bool)
         tele = self._telemetry
@@ -603,7 +615,7 @@ class RowGroupDecoderWorker:
         remaining = [f for f in self._read_fields if f not in pred_fields]
         if remaining:
             rest = self._load(parquet_file, item, remaining, mask=mask,
-                              row_range=row_range)
+                              row_range=row_range, ordinal=ordinal)
             columns = {**{f: pred_batch.columns[f][mask] for f in pred_fields},
                        **rest.columns}
         else:

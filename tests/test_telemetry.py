@@ -8,6 +8,7 @@ never change what it delivers).
 """
 
 import json
+import os
 import queue
 import threading
 import time
@@ -434,3 +435,204 @@ def test_jax_loader_records_transfer_stages(dataset):
     assert counters["stage.device-transfer.count"] == 6
     assert counters["stage.device-transfer.busy_s"] > 0
     assert counters["loader.batches_delivered"] == 6
+
+
+# -- spans on the profiler's clock ---------------------------------------------
+
+
+def _profiled_spans(trace_dir):
+    """``petastorm/*`` events of the host plane of the one xplane under
+    ``trace_dir``: [(line index, name, start_ns, end_ns, stats)]."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    out = []
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(T.PROFILER_PREFIX):
+                    out.append((i, e.name[len(T.PROFILER_PREFIX):],
+                                e.start_ns, e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return out
+
+
+def test_spans_land_in_a_running_profiler_session(tmp_path):
+    jax = pytest.importorskip("jax")
+    tele = T.Telemetry()
+
+    def worker():
+        # a path is no id: it stays on the host clock's span only
+        with tele.stage("decode", path="/d/a,b#c.parquet", ordinal=7,
+                        rowgroup=2):
+            with tele.stage("read", ordinal=7, rowgroup=2):
+                time.sleep(0.02)
+            time.sleep(0.01)
+
+    def transfer():
+        with tele.stage("device-transfer", batch=np.int64(3)):
+            with tele.span("put", batch=3):
+                time.sleep(0.005)
+            with tele.stage("commit", batch=3):
+                time.sleep(0.02)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tele.stage("host-prep", batch=3):
+            time.sleep(0.005)
+        threads = [threading.Thread(target=worker, name="w"),
+                   threading.Thread(target=transfer, name="t")]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        jax.profiler.stop_trace()
+    spans = {name: (line, a, b, stats)
+             for line, name, a, b, stats in _profiled_spans(tmp_path)}
+    assert set(spans) == {"host-prep", "decode", "read", "device-transfer",
+                          "put", "commit"}
+    line = {name: s[0] for name, s in spans.items()}
+    # each span on the line of the thread that ran it
+    assert line["read"] == line["decode"]
+    assert line["put"] == line["commit"] == line["device-transfer"]
+    assert len({line["host-prep"], line["decode"], line["commit"]}) == 3
+    assert spans["decode"][3] == {"ordinal": 7, "rowgroup": 2}
+    assert spans["read"][3] == {"ordinal": 7, "rowgroup": 2}
+    assert spans["commit"][3] == {"batch": 3}
+    assert spans["device-transfer"][3] == {"batch": 3}
+    # the children sit inside their parents
+    for child, parent in (("read", "decode"), ("commit", "device-transfer"),
+                          ("put", "device-transfer")):
+        assert spans[parent][1] <= spans[child][1]
+        assert spans[child][2] <= spans[parent][2]
+    # and each lasts what the host clock's span lasted
+    host = {e["name"]: e["dur_ms"] for e in tele.trace.tail(20)}
+    for name, (_, a, b, _) in spans.items():
+        assert (b - a) / 1e6 == pytest.approx(host[name], abs=2.0), name
+    # the counters are the stages' as ever
+    counters = tele.snapshot()["counters"]
+    assert counters["stage.read.count"] == counters["stage.commit.count"] == 1
+    assert counters["stage.decode.busy_s"] >= counters["stage.read.busy_s"]
+
+
+def test_null_telemetry_and_idle_profiler_emit_nothing(tmp_path):
+    jax = pytest.importorskip("jax")
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with T.NULL_TELEMETRY.stage("decode", ordinal=1):
+            with T.NULL_TELEMETRY.span("read", ordinal=1):
+                time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    assert _profiled_spans(tmp_path) == []
+    # an enabled recorder with no session running opens no annotation
+    assert T._profiler_mark("decode", {"ordinal": 1}) is None
+
+
+def test_telemetry_import_leaves_jax_unimported():
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys\n"
+            "import petastorm_tpu.telemetry as T\n"
+            "t = T.Telemetry()\n"
+            "with t.stage('decode', ordinal=1):\n"
+            "    with t.span('read', ordinal=1):\n"
+            "        pass\n"
+            "print('jax' in sys.modules)\n")
+    env = dict(os.environ, PYTHONPATH=root + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
+
+
+def test_jpeg_reader_fills_read_and_commit_stages(tmp_path):
+    pytest.importorskip("jax")
+    from petastorm_tpu.native import image as native_image
+
+    if not native_image.available():
+        pytest.skip("native image library unavailable")
+    from petastorm_tpu.codecs import CompressedImageCodec
+    from petastorm_tpu.etl.writer import write_dataset
+    from petastorm_tpu.jax import JaxDataLoader
+    from petastorm_tpu.schema import Field, Schema
+
+    schema = Schema("Jpeg", [
+        Field("idx", np.int64),
+        Field("image", np.uint8, (32, 48, 3),
+              CompressedImageCodec("jpeg", quality=90))])
+    rng = np.random.default_rng(0)
+    rows = [{"idx": i,
+             "image": rng.integers(0, 255, (32, 48, 3), dtype=np.uint8)}
+            for i in range(32)]
+    url = str(tmp_path / "ds")
+    write_dataset(url, schema, rows, row_group_size_rows=8)
+    tele = T.Telemetry()
+    with make_batch_reader(url, shuffle_row_groups=False, num_epochs=1,
+                           decode_placement={"image": "device"},
+                           reader_pool_type="thread", workers_count=2,
+                           telemetry=tele) as r:
+        with JaxDataLoader(r, batch_size=8, fields=["idx", "image"],
+                           transfer_commit=True) as loader:
+            delivered = sum(int(b["idx"].shape[0]) for b in loader)
+    assert delivered == 32
+    c = tele.snapshot()["counters"]
+    assert c["stage.read.count"] == c["stage.decode.count"] == 4
+    assert 0 < c["stage.read.busy_s"] <= c["stage.decode.busy_s"]
+    # the first commit rides the probe's warm-up; every batch commits
+    assert c["stage.commit.count"] == c["loader.batches_delivered"] == 4
+    assert 0 < c["stage.commit.busy_s"] <= c["stage.device-transfer.busy_s"]
+    names = {e["name"] for e in tele.trace.tail(500)}
+    assert {"device-decode", "put", "commit", "results-empty"} <= names
+    batches = {e["args"]["batch"] for e in tele.trace.tail(500)
+               if e["name"] == "commit"}
+    assert batches == {0, 1, 2, 3}
+
+
+def test_commit_stage_reads_zero_with_commits_off(dataset):
+    pytest.importorskip("jax")
+    from petastorm_tpu.jax.loader import JaxDataLoader
+
+    url, _ = dataset
+    tele = T.Telemetry()
+    reader = make_batch_reader(url, reader_pool_type="serial",
+                               shuffle_row_groups=False, telemetry=tele,
+                               schema_fields=["id"])
+    with JaxDataLoader(reader, batch_size=10,
+                       transfer_commit=False) as loader:
+        assert sum(1 for _ in loader) == 6
+    c = tele.snapshot()["counters"]
+    assert c["stage.commit.count"] == 0 and c["stage.commit.busy_s"] == 0
+    assert c["stage.read.count"] == c["stage.decode.count"] > 0
+
+
+def test_packed_loader_fills_pack_stage(tmp_path):
+    pytest.importorskip("jax")
+    from petastorm_tpu.sequence import make_packed_sequence_loader
+    from petastorm_tpu.test_util.synthetic import write_token_corpus
+
+    url = str(tmp_path / "corpus")
+    write_token_corpus(url, n_docs=60, rows_per_rg=10, mean_len=20,
+                       max_len=80, seed=5)
+    tele = T.Telemetry()
+    with make_packed_sequence_loader(url, batch_size=4, seq_len=64, seed=3,
+                                     workers_count=2,
+                                     telemetry=tele) as loader:
+        steps = sum(1 for _ in loader)
+    c = tele.snapshot()["counters"]
+    assert steps == c["loader.batches_delivered"] > 0
+    # one pack span per document fed
+    assert c["stage.pack.count"] == c["sequence.docs_packed"] == 60
+    assert c["stage.pack.busy_s"] > 0
+    assert c["stage.read.count"] == c["stage.decode.count"] == 6
+    assert c["stage.decode.busy_s"] >= c["stage.read.busy_s"]
