@@ -39,7 +39,7 @@ import logging
 import queue
 import threading
 import time
-from typing import Callable, Dict, Optional, Sequence, Tuple, Union
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import numpy as np
@@ -176,11 +176,16 @@ class JaxDataLoader:
     telemetry and ``diagnostics['straggler_releases']``.
 
     ``transfer_commit`` (default ``'auto'``): whether the transfer thread
-    blocks until each batch lands on device.  ``'auto'`` probes the
-    runtime's readiness-sync cost once and starts in ASYNC-CHAINED mode
-    (no per-batch commit) on runtimes where a sync is expensive;
-    ``True``/``False`` pin it.  The adaptive mid-run disable stays armed as
-    the backstop in 'auto' and True modes.
+    blocks until each batch's host->device copies land.  A commit waits on
+    what was copied from the host - the fields, the valid mask and, for
+    fields decoded on the chip, their coefficient planes - never on the
+    on-chip JPEG finish computed from them, which the device's stream order
+    runs before the step that reads it; so a fault inside the finish
+    surfaces where the consumer uses the array, not in the transfer thread.
+    ``'auto'`` probes the runtime's readiness-sync cost once and starts in
+    ASYNC-CHAINED mode (no per-batch commit) on runtimes where a sync is
+    expensive; ``True``/``False`` pin it.  The adaptive mid-run disable
+    stays armed as the backstop in 'auto' and True modes.
 
     Readers with ``decode_placement={'field': 'auto'}`` (the live
     host<->device decode split) are handled transparently: pixel-form and
@@ -227,6 +232,10 @@ class JaxDataLoader:
         self._m_consumer_wait = self._telemetry.counter(
             "loader.consumer_wait_s")
         self._m_delivered = self._telemetry.counter("loader.batches_delivered")
+        #: commits that left an on-chip computed field (the JPEG finish) to
+        #: the device's stream order (see _commit)
+        self._m_transfer_only = self._telemetry.counter(
+            "loader.commits_transfer_only")
         #: host-queue depth gauge: with the prefetch-depth gauge (set in
         #: __next__) the metrics sampler sees both producer stages' backlogs
         self._g_host_depth = self._telemetry.gauge("loader.host_queue_depth")
@@ -944,6 +953,7 @@ class JaxDataLoader:
                           if traced else _NULL_CONTEXT)
         with transfer_stage:
             device_batch = {}
+            sent: List[jax.Array] = []   # decode inputs copied from the host
             for name in self._device_decode:
                 if name in self._fields and not (
                         name in self._split_decode
@@ -955,8 +965,12 @@ class JaxDataLoader:
                               else self._decode_on_device)
                     with (tele.span("device-decode", batch=seq)
                           if traced else _NULL_CONTEXT):
-                        device_batch[name] = decode(name, host_batch.columns)
+                        device_batch[name], inputs = decode(
+                            name, host_batch.columns)
+                    sent.extend(inputs)
+            finishes = len(device_batch)
             with tele.span("put", batch=seq) if traced else _NULL_CONTEXT:
+                put: Dict[str, jax.Array] = {}
                 staged: Dict[str, np.ndarray] = {}
                 for name, col in cols.items():
                     arr = np.ascontiguousarray(col)
@@ -970,17 +984,18 @@ class JaxDataLoader:
                             name, arr.shape[1:])
                         # sequence/model-axis slice
                         arr = arr[(slice(None),) + sl[1:]]
-                        device_batch[name] = \
-                            jax.make_array_from_process_local_data(
-                                sharding, arr, global_shape)
+                        put[name] = jax.make_array_from_process_local_data(
+                            sharding, arr, global_shape)
                     else:
                         staged[name] = arr
                 if staged:
                     # ONE device_put for all fields: each call pays a fixed
                     # dispatch cost, so a small label column must not cost
                     # as much as the image column it rides with
-                    device_batch.update(jax.device_put(staged))
-            self._commit(device_batch, seq)
+                    put.update(jax.device_put(staged))
+            device_batch.update(put)
+            sent.extend(put.values())
+            self._commit(sent, seq, finishes)
         for name in self._host_fields:
             device_batch[name] = host_batch.columns[name]
         if self._mesh is not None and valid_rows < self._local_rows:
@@ -1032,6 +1047,7 @@ class JaxDataLoader:
                           if traced else _NULL_CONTEXT)
         with transfer_stage:
             device_batch = {}
+            sent: List[jax.Array] = []   # decode inputs copied from the host
             for name in self._device_decode:
                 if name in self._fields:
                     decode = (self._decode_mixed_stack
@@ -1039,9 +1055,12 @@ class JaxDataLoader:
                               else self._decode_stack)
                     with (tele.span("device-decode", batch=seq)
                           if traced else _NULL_CONTEXT):
-                        device_batch[name] = decode(name, group)
+                        device_batch[name], inputs = decode(name, group)
+                    sent.extend(inputs)
+            finishes = len(device_batch)
 
             with tele.span("put", batch=seq) if traced else _NULL_CONTEXT:
+                put: Dict[str, jax.Array] = {}
                 staged: Dict[str, np.ndarray] = {}
                 for name in (list(prepped[0]) if prepped else []):
                     steps = [np.ascontiguousarray(p[name]) for p in prepped]
@@ -1056,9 +1075,8 @@ class JaxDataLoader:
                         sharding, sl, global_shape = self._placement_for(
                             name, arr.shape[2:])
                         arr = arr[(slice(None), slice(None)) + sl[2:]]
-                        device_batch[name] = \
-                            jax.make_array_from_process_local_data(
-                                sharding, arr, global_shape)
+                        put[name] = jax.make_array_from_process_local_data(
+                            sharding, arr, global_shape)
                     else:
                         staged[name] = arr
                 if self._valid_mask is not None:
@@ -1068,14 +1086,16 @@ class JaxDataLoader:
                     name = self._valid_mask
                     self._emitted_layout[name] = ((), np.dtype(np.float32))
                     sharding, _, global_shape = self._placement_for(name, ())
-                    device_batch[name] = jax.make_array_from_process_local_data(
+                    put[name] = jax.make_array_from_process_local_data(
                         sharding, mask, global_shape)
                 if staged:
                     # ONE device_put for the whole stack: K steps of data
                     # ride a single fixed-cost dispatch instead of K (the
                     # whole point)
-                    device_batch.update(jax.device_put(staged))
-            self._commit(device_batch, seq)
+                    put.update(jax.device_put(staged))
+            device_batch.update(put)
+            sent.extend(put.values())
+            self._commit(sent, seq, finishes)
         for name in self._host_fields:
             steps = [_pad_host_col(hb.columns[name], local) for hb in group]
             steps += [_host_filler(steps[-1])] * (K - real_steps)
@@ -1085,11 +1105,23 @@ class JaxDataLoader:
                 valids + [0] * (K - real_steps), dtype=np.int64)
         self._push(device_batch)
 
-    def _commit(self, device_batch, seq: int) -> None:
+    def _commit(self, sent: List[jax.Array], seq: int,
+                finishes: int) -> None:
         """Commit the transfers in the transfer thread: the consumer then
         never blocks on a half-copied array, and its readiness query never
         queues behind the next batch's dispatch (serialized device RPC
         channels would otherwise surface that contention as input stall).
+
+        What is committed is ``sent``: the arrays this batch copied from the
+        host - the put fields, the valid mask, and the coefficient planes
+        and quantisation tables the on-chip JPEG finish reads.  The
+        ``finishes`` fields computed on the chip from them (the finish's
+        output and whatever is derived from it there) are NOT waited on: the
+        device's stream order already runs a batch's finish before the step
+        that reads it, and waiting on it would hold the transfer thread
+        until the step already in flight ends - the feed would then never
+        run ahead of the step.  A fault inside the on-chip finish therefore
+        surfaces where the consumer uses the array, not here.
 
         DEFAULT (transfer_commit='auto'): the readiness-sync cost is probed
         ONCE before the first commit - one warm trivial op timed three times
@@ -1113,17 +1145,17 @@ class JaxDataLoader:
         tele = self._telemetry
         t0 = time.perf_counter()
         # the 'commit' stage: the transfer thread waiting on the batch's
-        # arrays, the on-chip decode finish included
+        # host->device copies
         with (tele.stage("commit", batch=seq) if tele.enabled
               else _NULL_CONTEXT):
-            jax.block_until_ready(device_batch)
+            jax.block_until_ready(sent)
         took = time.perf_counter() - t0
+        if finishes:
+            self._m_transfer_only.add(1)
         self._commit_count += 1
         if self._commit_count == 1:
             return  # first commit carries one-time executable warmup cost
-        nbytes = sum(getattr(v, "nbytes", 0)
-                     for v in device_batch.values()
-                     if isinstance(v, jax.Array))
+        nbytes = sum(v.nbytes for v in sent)
         # generous floor: 100 MB/s sustained transfer + 100 ms fixed is
         # slower than any healthy runtime; beyond it the sync itself is the
         # cost, not the copy.  Two CONSECUTIVE breaches are required so a
@@ -1170,11 +1202,13 @@ class JaxDataLoader:
                 " transfer (no per-batch commit). transfer_commit=True"
                 " overrides.", cost * 1e3)
 
-    def _decode_stack(self, name: str, group) -> jax.Array:
+    def _decode_stack(self, name: str, group
+                      ) -> Tuple[jax.Array, List[jax.Array]]:
         """Stack-mode variant of ``_decode_on_device``: the K batches'
         coefficient planes ship as ONE ``(K, local, ...)`` transfer and the
         on-chip dequant+IDCT+upsample+color runs once over the whole stack
-        (``ops/jpeg.decode_coefficients`` handles leading batch dims)."""
+        (``ops/jpeg.decode_coefficients`` handles leading batch dims).
+        Returns the decoded field and the arrays copied from the host."""
         from petastorm_tpu.native.image import unpack_coef_columns
         from petastorm_tpu.ops.jpeg import decode_coefficients
 
@@ -1222,13 +1256,15 @@ class JaxDataLoader:
                     out, NamedSharding(self._mesh, PartitionSpec(None, *spec)))
         if len(field.shape) == 3 and field.shape[2] == 1 and out.ndim == 4:
             out = out[..., None]  # honor a declared (H, W, 1) grayscale shape
-        return out
+        return out, [*jp, jq]
 
-    def _decode_mixed_stack(self, name: str, group) -> jax.Array:
+    def _decode_mixed_stack(self, name: str, group
+                            ) -> Tuple[jax.Array, List[jax.Array]]:
         """Stack-mode variant of ``_decode_mixed_on_device``: the K batches'
         cells decode as one flat ``K*local``-row bucket pass (host-local, as
         ever), then reshape to ``(K, local, ...)`` and scatter along the
-        batch axis."""
+        batch axis.  Returns the decoded field and the arrays copied from
+        the host."""
         import jax.numpy as jnp
 
         from petastorm_tpu.native.image import (COEF_COLUMN_SEP,
@@ -1238,7 +1274,7 @@ class JaxDataLoader:
         key = f"{name}{COEF_COLUMN_SEP}{MIXED_CELL_SUFFIX}"
         flat = np.concatenate([hb.columns[key] for hb in group])
         n = len(flat)   # real cells form a prefix: only the LAST batch is short
-        out = self._decode_mixed_flat(name, flat, K * local)
+        out, sent = self._decode_mixed_flat(name, flat, K * local)
         field = self._schema[name]
         if len(field.shape) == 3 and field.shape[2] == 1 and out.ndim == 3:
             out = out[..., None]
@@ -1248,7 +1284,7 @@ class JaxDataLoader:
         out = out.reshape((K, local) + out.shape[1:])
         if self._mesh is not None:
             out = self._scatter_stacked_rows(name, out)
-        return out
+        return out, sent
 
     def _scatter_stacked_rows(self, name: str, out) -> jax.Array:
         """(K, local, ...) host-local decoded rows -> one global mesh array
@@ -1257,7 +1293,7 @@ class JaxDataLoader:
         return self._scatter_batch_axis(name, out, lead=1)
 
     def _decode_mixed_on_device(self, name: str, columns: Dict[str, np.ndarray]
-                                ) -> jax.Array:
+                                ) -> Tuple[jax.Array, List[jax.Array]]:
         """Finish the hybrid decode of a MIXED-geometry field
         (decode_placement='device-mixed').
 
@@ -1267,7 +1303,8 @@ class JaxDataLoader:
         log2(batch), see ``_decode_mixed_flat``), decoded, fitted
         (pad/crop) to the static target, then scattered back into batch
         order.  The wasted FLOPs on the padding rows are cheap: the on-chip
-        half is small next to the host entropy decode.
+        half is small next to the host entropy decode.  Returns the decoded
+        field and the arrays copied from the host.
         """
         from petastorm_tpu.native.image import (COEF_COLUMN_SEP,
                                                 MIXED_CELL_SUFFIX)
@@ -1275,14 +1312,16 @@ class JaxDataLoader:
         field = self._schema[name]
         col = columns[f"{name}{COEF_COLUMN_SEP}{MIXED_CELL_SUFFIX}"]
         n = len(col)
-        out = self._decode_mixed_flat(name, col, max(self._local_rows, n))
+        out, sent = self._decode_mixed_flat(name, col,
+                                            max(self._local_rows, n))
         if len(field.shape) == 3 and field.shape[2] == 1 and out.ndim == 3:
             out = out[..., None]
         if self._mesh is not None:
             out = self._scatter_local_rows(name, out, n)
-        return out
+        return out, sent
 
-    def _decode_mixed_flat(self, name: str, col, batch_pad: int) -> jax.Array:
+    def _decode_mixed_flat(self, name: str, col, batch_pad: int
+                           ) -> Tuple[jax.Array, List[jax.Array]]:
         """Bucket-decode one flat column of mixed-geometry cells.  Each
         bucket pads its group to the next power of two (min 8, capped at
         ``batch_pad``) - NOT to the full batch: padding every bucket to
@@ -1293,7 +1332,9 @@ class JaxDataLoader:
         bounded by geometries x log2(batch) (decode/fit) plus the distinct
         per-batch size compositions (concat/gather).  Returns
         ``(len(col), *target)`` rows in column order, on the default device
-        (the decode is host-local; mesh placement happens after)."""
+        (the decode is host-local; mesh placement happens after), and the
+        arrays copied from the host: each bucket's planes and tables, and
+        the gather index."""
         import jax.numpy as jnp
 
         from petastorm_tpu.native.image import _layout_from_meta
@@ -1306,6 +1347,7 @@ class JaxDataLoader:
             groups.setdefault(cell[2].tobytes(), []).append(i)
         self._mixed_geometries.setdefault(name, set()).update(groups)
         parts = []
+        sent: List[jax.Array] = []
         flat_idx = np.empty(n, dtype=np.int64)
         offset = 0
         for key, idxs in groups.items():
@@ -1328,6 +1370,7 @@ class JaxDataLoader:
                                     qtabs.dtype)])
             sampling = tuple((h, v) for (h, v, _, _) in layout.components)
             jp, jq = jax.device_put((tuple(planes), qtabs))
+            sent += [*jp, jq]
             img = decode_coefficients(jp, jq,
                                       image_size=(layout.height, layout.width),
                                       sampling=sampling)
@@ -1363,7 +1406,9 @@ class JaxDataLoader:
                    else parts[0])
         # one static-shape gather scatters rows back into batch order and
         # drops the pad rows in the same pass
-        return stacked[jnp.asarray(flat_idx)]
+        idx = jax.device_put(flat_idx)
+        sent.append(idx)
+        return stacked[idx], sent
 
     def _check_declared_geometry(self, name: str, layout) -> None:
         """Warn (once per geometry) when a batch reveals an image geometry
@@ -1437,7 +1482,7 @@ class JaxDataLoader:
             global_shape, sharding, shards)
 
     def _decode_on_device(self, name: str, columns: Dict[str, np.ndarray]
-                          ) -> jax.Array:
+                          ) -> Tuple[jax.Array, List[jax.Array]]:
         """Finish the hybrid jpeg decode of one field (decode_placement='device').
 
         The entropy half already ran in the pool workers - ``columns`` holds
@@ -1445,7 +1490,8 @@ class JaxDataLoader:
         native/image.py pack_coef_columns).  Here the planes ship to the
         device(s) batch-sharded and the FLOP-heavy dequant + IDCT + upsample +
         color runs on-chip, sharded, with no cross-shard communication
-        (petastorm_tpu/ops/jpeg.py).
+        (petastorm_tpu/ops/jpeg.py).  Returns the decoded field and the
+        arrays copied from the host (the planes and quantisation tables).
         """
         from petastorm_tpu.native.image import unpack_coef_columns
         from petastorm_tpu.ops.jpeg import decode_coefficients
@@ -1484,7 +1530,7 @@ class JaxDataLoader:
                 out = jax.device_put(out, NamedSharding(self._mesh, spec))
         if len(field.shape) == 3 and field.shape[2] == 1 and out.ndim == 3:
             out = out[..., None]  # honor a declared (H, W, 1) grayscale shape
-        return out
+        return out, [*jp, jq]
 
     def _delivery_spec(self, name: str) -> PartitionSpec:
         """The PartitionSpec a delivered array for ``name`` actually uses:

@@ -623,6 +623,117 @@ def test_transfer_commit_rejects_bad_value():
         JaxDataLoader(reader, batch_size=8, transfer_commit=1)
 
 
+#: decode placement -> (schema image shape, the geometries rows cycle through)
+_COMMIT_DATASETS = {"device": ((16, 24, 3), [(16, 24)]),
+                    "device-mixed": ((None, None, 3), [(16, 24), (24, 16)])}
+_MIXED_TARGET = (24, 24, 3)
+
+
+@pytest.fixture(scope="module")
+def commit_datasets(tmp_path_factory):
+    root = tmp_path_factory.mktemp("commit")
+    urls = {}
+    for placement, (shape, geometries) in _COMMIT_DATASETS.items():
+        schema = Schema("Commit", [
+            Field("label", np.int64, (), ScalarCodec()),
+            Field("image", np.uint8, shape,
+                  CompressedImageCodec("jpeg", quality=90))])
+        rows = [{"label": i,
+                 "image": synthetic_rgb_image(
+                     i, *geometries[i % len(geometries)])}
+                for i in range(32)]
+        urls[placement] = str(root / placement)
+        write_dataset(urls[placement], schema, rows, row_group_size_rows=8)
+    return urls
+
+
+def _commit_run(url, placement, commit, telemetry=None, **loader_kwargs):
+    from petastorm_tpu.jax import JaxDataLoader
+
+    if placement == "device-mixed":
+        loader_kwargs["pad_shapes"] = {"image": _MIXED_TARGET}
+    with make_batch_reader(url, shuffle_row_groups=False, num_epochs=1,
+                           workers_count=1, telemetry=telemetry,
+                           decode_placement={"image": placement}) as r:
+        with JaxDataLoader(r, batch_size=8, fields=["label", "image"],
+                           transfer_commit=commit, **loader_kwargs) as ld:
+            return list(ld)
+
+
+def _rows_by_label(units):
+    labels = np.concatenate([np.asarray(u["label"]).ravel() for u in units])
+    images = np.concatenate([np.asarray(u["image"]).reshape(
+        (-1,) + u["image"].shape[-3:]) for u in units])
+    order = np.argsort(labels, kind="stable")
+    return labels[order], images[order]
+
+
+@pytest.mark.parametrize("stack", [1, 2])
+@pytest.mark.parametrize("placement", ["device", "device-mixed"])
+@pytest.mark.parametrize("on_mesh", [False, True],
+                         ids=["no-mesh", "one-device-mesh"])
+def test_commit_waits_on_transfers_not_on_the_finish(
+        commit_datasets, monkeypatch, on_mesh, placement, stack):
+    """A commit blocks on what the batch copied from the host - the
+    coefficient planes and tables, the put fields - and never on the
+    on-chip JPEG finish computed from them; the delivered rows are those
+    of a run without commits."""
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from petastorm_tpu.jax import loader as loader_mod
+
+    kwargs = {"stack_batches": stack}
+    if on_mesh:
+        kwargs["mesh"] = Mesh(np.array(jax.devices()[:1]), ("data",))
+        kwargs["shardings"] = {"label": P("data"), "image": P("data")}
+    url = commit_datasets[placement]
+
+    committed = []
+    real = loader_mod.jax.block_until_ready
+
+    def recording(x):
+        committed.append(jax.tree_util.tree_leaves(x))
+        return real(x)
+
+    monkeypatch.setattr(loader_mod.jax, "block_until_ready", recording)
+    units = _commit_run(url, placement, True, **kwargs)
+    monkeypatch.undo()
+
+    # transfer_commit=True skips the probe: one commit per delivered unit,
+    # in delivery order
+    assert len(units) == 32 // (8 * stack) == len(committed)
+    for unit, leaves in zip(units, committed):
+        assert all(leaf is not unit["image"] for leaf in leaves)
+        assert not any(leaf.dtype == np.uint8 for leaf in leaves)
+        assert any(leaf is unit["label"] for leaf in leaves)
+        planes = [leaf for leaf in leaves
+                  if leaf.dtype == np.int16 and leaf.shape[-1] == 64]
+        tables = [leaf for leaf in leaves if leaf.dtype == np.uint16]
+        # three colour planes per table: one set per geometry bucket
+        assert planes and len(planes) == 3 * len(tables)
+
+    labels, images = _rows_by_label(units)
+    want_labels, want_images = _rows_by_label(
+        _commit_run(url, placement, False, **kwargs))
+    assert labels.tolist() == list(range(32))
+    np.testing.assert_array_equal(labels, want_labels)
+    np.testing.assert_array_equal(images, want_images)
+
+
+@pytest.mark.parametrize("placement", ["device", "host"])
+def test_commits_transfer_only_counts_batches_with_a_finish(
+        commit_datasets, placement):
+    tele = Telemetry()
+    units = _commit_run(commit_datasets["device"], placement, True,
+                        telemetry=tele)
+    assert len(units) == 4
+    c = tele.snapshot()["counters"]
+    assert c["loader.batches_delivered"] == 4
+    assert c["loader.commits_transfer_only"] == (
+        4 if placement == "device" else 0)
+
+
 def test_roi_fallback_passes_nulls_through():
     """A nullable image column under decode_roi must not crash on None
     cells (the per-cell fallback path decodes them as None)."""
