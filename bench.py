@@ -362,11 +362,11 @@ def bench_imagenet(tmp):
 
 def bench_imagenet_mixed(tmp):
     """device-mixed on the REAL chip: a 2-geometry jpeg
-    dataset through the bucket-pad-scatter decode, with the same-session
-    host decode of the SAME mixed data in the note (and the uniform-device
+    dataset through the canvas finish, with the host decode of the SAME
+    mixed data, measured just before, in the note (and the uniform-device
     number from bench_imagenet for cross-reference).  Round 4 proved mixed
-    decode works; this proves the bucketing does not give the hybrid win
-    back."""
+    decode works; this shows whether the canvas padding gives the hybrid
+    win back."""
     _require_device_runtime()
     import numpy as np
 
@@ -433,7 +433,7 @@ def bench_imagenet_mixed(tmp):
         "imagenet_ingest_mixed_samples_per_sec", mixed_rate, "samples/sec",
         max(host_rate, 1e-6),
         note=f"2-geometry jpeg dataset {geoms} via device-mixed"
-             f" (bucket-pad-scatter), pad target {target}; vs_baseline ="
+             f" (canvas finish), canvas {target}; vs_baseline ="
              " ratio to the same-session HOST decode of the SAME mixed data"
              f" ({host_rate:.0f} samples/s - the drift-immune anchor);"
              f" uniform-geometry device decode this session:"

@@ -99,9 +99,10 @@ API = [
     ("petastorm_tpu.ops.normalize", ["normalize_images"]),
     ("petastorm_tpu.ops.augment", ["random_crop", "random_flip",
                                    "random_crop_flip", "random_resized_crop",
-                                   "resize_images", "mixup", "cutmix"]),
-    ("petastorm_tpu.ops.jpeg", ["decode_coefficients", "decode_from_layout",
-                              "decode_jpeg_column"]),
+                                   "crop_boxes", "resize_images", "mixup",
+                                   "cutmix"]),
+    ("petastorm_tpu.ops.jpeg", ["decode_coefficients", "finish_canvas",
+                                "decode_from_layout", "decode_jpeg_column"]),
     ("petastorm_tpu.ops.ring_attention", ["ring_attention", "ring_attention_sharded"]),
     ("petastorm_tpu.ops.ulysses", ["ulysses_attention", "ulysses_attention_sharded"]),
     ("petastorm_tpu.parallel.mesh", ["local_data_slice", "shard_options_from_jax",

@@ -37,9 +37,9 @@ logger = logging.getLogger(__name__)
 #: Parquet KV key: JSON ``{"files": {relative_path: [rows_in_rg0, rows_in_rg1, ...]}}``
 ROW_GROUPS_METADATA_KEY = b"petastorm-tpu.row_groups_per_file.v1"
 #: per-field distinct image shapes, stamped at write/copy time: the
-#: DATASET-LEVEL geometry contract that bounds on-device mixed-geometry
-#: decode compiles (every geometry a reader can possibly encounter is known
-#: up front - jax/loader.py 'device-mixed').  JSON {field: [[h, w, c], ...]}.
+#: DATASET-LEVEL geometry contract (every geometry a reader can possibly
+#: encounter is known up front; jax/loader.py 'device-mixed' refuses
+#: canvases that cannot hold one).  JSON {field: [[h, w, c], ...]}.
 GEOMETRIES_METADATA_KEY = b"petastorm-tpu.image_geometries.v1"
 #: Parquet KV key: JSON rowgroup index (petastorm_tpu/etl/indexing.py)
 ROWGROUP_INDEX_METADATA_KEY = b"petastorm-tpu.rowgroup_index.v1"
@@ -349,8 +349,9 @@ def declared_geometries(info: "DatasetInfo") -> Dict[str, List[tuple]]:
 
     Stamped by ``write_dataset``/``stamp_dataset_metadata`` for
     variable-shape ``CompressedImageCodec`` fields; consumed by the jax
-    loader's ``decode_placement='device-mixed'`` path as the dataset-level
-    bound on decode compiles (and surfaced in loader diagnostics)."""
+    loader's ``decode_placement='device-mixed'`` path, which checks that its
+    largest canvas holds every declared geometry (and surfaced in loader
+    diagnostics)."""
     raw = info.kv_metadata.get(GEOMETRIES_METADATA_KEY)
     if not raw:
         return {}

@@ -187,8 +187,8 @@ def write_dataset(url: str,
 
     # dataset-level geometry contract: record the distinct image shapes of
     # variable-shape CompressedImageCodec fields while the rows stream by, so
-    # readers know EVERY geometry up front (bounds the on-device mixed-decode
-    # compile count; jax loader 'device-mixed')
+    # readers know EVERY geometry up front (the jax loader's 'device-mixed'
+    # canvases must hold them all)
     from petastorm_tpu.codecs import CompressedImageCodec
 
     geom_fields = [f.name for f in schema

@@ -35,6 +35,7 @@ TPU-specific behavior:
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import queue
 import threading
@@ -49,6 +50,7 @@ from petastorm_tpu.batch import ColumnBatch
 from petastorm_tpu.dtypes import jax_feed_dtype
 from petastorm_tpu.errors import PetastormTpuError
 from petastorm_tpu.native.image import COEF_COLUMN_SEP as _COEF_SEP
+from petastorm_tpu.native.image import MIXED_CELL_SUFFIX as _MIXED_SUFFIX
 from petastorm_tpu.parallel.mesh import local_data_slice
 from petastorm_tpu.shuffle import (NoopShufflingBuffer, RandomShufflingBuffer,
                                    iter_batched, iter_batched_multi)
@@ -66,10 +68,75 @@ _DEFAULT_STRAGGLER_RELEASE_S = 2.0
 #: trivial op marks a runtime where a sync is expensive - async chaining then
 #: pipelines strictly better
 _COMMIT_PROBE_THRESHOLD_S = 0.02
+#: key suffix of the per-image (height, width) that rides a batch beside
+#: each 'device-mixed' field (``'image'`` -> ``'image_extent'``)
+EXTENT_SUFFIX = "_extent"
+#: images per on-chip finish call of a 'device-mixed' field: a canvas's
+#: images go in groups of the largest size, the rest in the smallest size
+#: that holds them (sizes above the local batch are cut to it)
+_MIXED_GROUP_SIZES = (8, 16, 32)
 
 
 class _Done:
     pass
+
+
+def _mixed_key(name: str) -> str:
+    """The wire column of a 'device-mixed' field (object cells from the
+    workers, then a ``_MixedPack`` from the assembly thread)."""
+    return f"{name}{_COEF_SEP}{_MIXED_SUFFIX}"
+
+
+def _canvas_plane_shapes(canvas: Tuple[int, int],
+                         sampling: Tuple[Tuple[int, int], ...]):
+    """Per component, the (blocks_h, blocks_w) of a canvas rounded up to
+    whole MCUs: it then holds the blocks of any image no larger than it."""
+    max_h = max(h for h, _ in sampling)
+    max_v = max(v for _, v in sampling)
+    height = -(-canvas[0] // (8 * max_v)) * max_v
+    width = -(-canvas[1] // (8 * max_h)) * max_h
+    return [(height * v // max_v, width * h // max_h) for h, v in sampling]
+
+
+class _CanvasGroup:
+    """Host arrays of one on-chip finish call: ``len(rows)`` images (rows
+    of -1 are padding) in one canvas and chroma layout."""
+
+    __slots__ = ("canvas", "sampling", "planes", "qtabs", "extents", "rows")
+
+    def __init__(self, canvas, sampling, planes, qtabs, extents, rows):
+        self.canvas, self.sampling = canvas, sampling
+        self.planes, self.qtabs = planes, qtabs
+        self.extents, self.rows = extents, rows
+
+    @classmethod
+    def pack(cls, col, members, extents, canvas, sampling, size):
+        planes = tuple(np.zeros((size, bh, bw, 64), np.int16)
+                       for bh, bw in _canvas_plane_shapes(canvas, sampling))
+        qtabs = np.ones((size, len(planes), 64), np.uint16)
+        ext = np.zeros((size, 2), np.int32)
+        rows = np.full(size, -1, np.int32)
+        for k, i in enumerate(members):
+            cell_planes, qtab, _ = col[i]
+            for dst, src in zip(planes, cell_planes):
+                dst[k, :src.shape[0], :src.shape[1]] = src
+            qtabs[k] = qtab
+            ext[k] = extents[i]
+            rows[k] = i
+        return cls(canvas, sampling, planes, qtabs, ext, rows)
+
+
+class _MixedPack:
+    """A batch's 'device-mixed' column after the 'mixed-pack' stage: each
+    row's (height, width) and the canvas groups that finish them."""
+
+    __slots__ = ("extents", "groups")
+
+    def __init__(self, extents: np.ndarray, groups: List[_CanvasGroup]):
+        self.extents, self.groups = extents, groups
+
+    def __len__(self) -> int:
+        return len(self.extents)
 
 
 class _Error:
@@ -164,7 +231,25 @@ class JaxDataLoader:
     rows zero-pad, ``'_valid_rows'`` becomes a per-step ``(K,)`` int array,
     the valid mask is ``(K, batch)``, and ``drain()``/``state_dict()`` count
     whole stacks.  Incompatible with ``device_shuffle_capacity`` and
-    multi-bucket ``pad_shapes``.
+    multi-bucket ``pad_shapes`` (a 'device-mixed' field's canvases excepted:
+    its delivered shape is fixed).
+
+    A ``decode_placement='device-mixed'`` field (JPEGs of any geometry) is
+    finished on the chip in fixed canvases: its ``pad_shapes`` entry (one
+    ``(H, W[, C])`` or a list of them; a fixed-shape field uses its schema
+    shape) names the canvases, and the largest, which must hold every
+    other, is the delivered shape.  The host pads each image's coefficient
+    planes top-left into the smallest canvas that holds it (the
+    ``mixed-pack`` stage), and the chip finishes groups of images one
+    canvas at a time.  Inside each image's extent the pixels are those of
+    the image's own decode; outside it they are 0.  The batch carries each
+    image's ``(height, width)`` as an int32 ``(batch, 2)`` array under
+    ``'<field>_extent'``, sharded on the batch axis like the field.  An
+    image larger than the largest canvas is an error.  The finish compiles
+    once per (chroma layout, canvas, group size of 8, 16 or 32 images):
+    all of a layout's at the batch where the layout first shows, so the
+    count of programs (``loader.mixed_finish_programs``) never depends on
+    how many geometries the data holds.
 
     ``straggler_release_s`` (MinatoLoader-style, default ``'auto'``): when
     no raw batch arrives for this long while the shuffle buffer already
@@ -236,6 +321,15 @@ class JaxDataLoader:
         #: the device's stream order (see _commit)
         self._m_transfer_only = self._telemetry.counter(
             "loader.commits_transfer_only")
+        #: 'device-mixed' fields: pixels of the images' own extents and of
+        #: the canvases they were packed into, and the finish programs
+        #: compiled (fixed per chroma layout, see _finish_program)
+        self._m_mixed_valid = self._telemetry.counter(
+            "loader.mixed_pixels_valid")
+        self._m_mixed_canvas = self._telemetry.counter(
+            "loader.mixed_pixels_canvas")
+        self._m_mixed_programs = self._telemetry.counter(
+            "loader.mixed_finish_programs")
         #: host-queue depth gauge: with the prefetch-depth gauge (set in
         #: __next__) the metrics sampler sees both producer stages' backlogs
         self._g_host_depth = self._telemetry.gauge("loader.host_queue_depth")
@@ -275,7 +369,7 @@ class JaxDataLoader:
         #: decoded on-chip in _emit via ops/jpeg.decode_coefficients
         self._device_decode = list(getattr(reader, "device_decode_fields", ()) or ())
         #: subset using the mixed-geometry wire format ('device-mixed'):
-        #: decoded per geometry bucket, padded to a static target
+        #: packed into fixed canvases, finished per canvas group
         self._mixed_decode = frozenset(
             getattr(reader, "device_decode_mixed", ()) or ())
         #: subset under the LIVE host<->device decode split
@@ -328,9 +422,11 @@ class JaxDataLoader:
                 f"transfer_commit must be True, False or 'auto';"
                 f" got {transfer_commit!r}")
         self._commit_mode = transfer_commit
-        #: geometries seen per mixed field (diagnostics; tests assert the
-        #: decode compile count stays bounded by this set's size)
-        self._mixed_geometries: Dict[str, set] = {}
+        #: per mixed field, layout meta bytes -> JpegCoefLayout of each
+        #: geometry seen (parsed once; counted in diagnostics)
+        self._mixed_geometries: Dict[str, Dict[bytes, object]] = {}
+        #: (field, sampling, canvas, group size) -> compiled on-chip finish
+        self._finish_programs: Dict[Tuple, object] = {}
         #: (field, h, w) geometries already warned about as missing from the
         #: dataset-level declared-geometry contract (one warning each)
         self._geom_warned: set = set()
@@ -360,6 +456,19 @@ class JaxDataLoader:
             raise PetastormTpuError(
                 "JaxDataLoader needs at least one device-deliverable field"
                 " (all schema fields were excluded or routed to host_fields)")
+        #: delivered 'device-mixed' field -> the key of its per-image extents
+        self._extent_keys = {name: name + EXTENT_SUFFIX for name in self._fields
+                             if name in self._mixed_decode}
+        self._extent_field = {k: f for f, k in self._extent_keys.items()}
+        clash = [k for k in self._extent_keys.values() if k in schema]
+        if clash:
+            raise PetastormTpuError(
+                f"'device-mixed' fields deliver their image extents under"
+                f" {clash}, which the schema already names; rename the field")
+        if self._extent_keys and self._telemetry.enabled:
+            register = getattr(self._telemetry, "register_stage", None)
+            if register is not None:
+                register("mixed-pack")
 
         #: synthetic per-row validity column (1.0 = real row, 0.0 = padding).
         #: Unlike the host-local '_valid_rows' int, the mask is a GLOBAL device
@@ -405,7 +514,8 @@ class JaxDataLoader:
                 " with decode_placement='host'/'device' for scan-feed"
                 " delivery.")
         if self._stack > 1:
-            bucketed = [n for n, b in self._pad_shapes.items() if len(b) > 1]
+            bucketed = [n for n, b in self._pad_shapes.items()
+                        if len(b) > 1 and n not in self._mixed_decode]
             if bucketed:
                 raise PetastormTpuError(
                     f"stack_batches={self._stack} needs one static shape per"
@@ -439,7 +549,8 @@ class JaxDataLoader:
                     " host_fields: host-side values cannot live in the HBM"
                     " buffer. Use the host shuffling buffer"
                     " (shuffling_queue_capacity) instead.")
-            bucketed = [n for n, b in self._pad_shapes.items() if len(b) > 1]
+            bucketed = [n for n, b in self._pad_shapes.items()
+                        if len(b) > 1 and n not in self._mixed_decode]
             if bucketed:
                 raise PetastormTpuError(
                     f"device_shuffle_capacity needs uniform batch shapes, but"
@@ -566,32 +677,50 @@ class JaxDataLoader:
 
     # -- shape/sharding bookkeeping ------------------------------------------
 
-    def _mixed_target(self, name: str) -> Tuple[int, ...]:
-        """Static (H, W[, C]) every decoded image of a 'device-mixed' field is
-        padded/cropped to: the schema shape when fixed, else a SINGLE
-        pad_shapes target (XLA compiles the fit once per geometry x target,
-        so the target must be static)."""
+    def _mixed_canvases(self, name: str) -> List[Tuple[int, int]]:
+        """(H, W) canvases of a 'device-mixed' field, smallest area first:
+        the schema shape when fixed, else its ``pad_shapes`` entry (one
+        target or a list).  The last holds every other and is the delivered
+        (H, W)."""
         field = self._schema[name]
         if field.is_fixed_shape:
-            return tuple(field.shape)
+            return [tuple(field.shape[:2])]
         buckets = self._pad_shapes.get(name)
-        if not buckets or len(buckets) != 1:
+        if not buckets:
             raise PetastormTpuError(
                 f"decode_placement='device-mixed' field {name!r} has variable"
-                f" shape {field.shape}: give it ONE pad_shapes target (H, W"
-                "[, C]) so every geometry bucket decodes+pads to a static"
-                " shape" + (f"; got {len(buckets)} buckets" if buckets else ""))
-        target = tuple(buckets[0])
-        if len(target) != len(field.shape):
+                f" shape {field.shape}: give it pad_shapes canvases (one"
+                " (H, W[, C]) or a list of them) that every image fits into")
+        for b in buckets:
+            if len(b) != len(field.shape):
+                raise PetastormTpuError(
+                    f"pad_shapes[{name!r}] canvas {tuple(b)} rank differs"
+                    f" from the field shape {field.shape}")
+        if len({tuple(b[2:]) for b in buckets}) > 1:
             raise PetastormTpuError(
-                f"pad_shapes[{name!r}] target {target} rank differs from the"
-                f" field shape {field.shape}")
-        return target
+                f"pad_shapes[{name!r}] canvases {buckets} disagree on the"
+                " channel count")
+        canvases = sorted({(int(b[0]), int(b[1])) for b in buckets},
+                          key=lambda c: (c[0] * c[1], c))
+        largest = (max(c[0] for c in canvases), max(c[1] for c in canvases))
+        if canvases[-1] != largest:
+            raise PetastormTpuError(
+                f"pad_shapes[{name!r}] canvases {canvases}: the largest must"
+                f" hold every other (add {largest}); it is the delivered shape")
+        return canvases
+
+    def _mixed_target(self, name: str) -> Tuple[int, ...]:
+        """Static (H, W[, C]) a 'device-mixed' field is delivered at: its
+        largest canvas."""
+        field = self._schema[name]
+        tail = (tuple(field.shape[2:]) if field.is_fixed_shape
+                else tuple(self._pad_shapes[name][0][2:]))
+        return self._mixed_canvases(name)[-1] + tail
 
     def _validate_deliverable(self, schema) -> None:
         for name in self._fields:
             if name in self._mixed_decode:
-                self._mixed_target(name)  # raises when no static target exists
+                self._check_canvases_hold_declared(name)
                 if self._mesh is not None:
                     # mesh delivery works because the decode stays HOST-LOCAL
                     # (each host compiles only the geometries it encounters -
@@ -621,6 +750,11 @@ class JaxDataLoader:
                     " or exclude it.")
 
     def _spec_for(self, name: str) -> PartitionSpec:
+        field = self._extent_field.get(name)
+        if field is not None:
+            # a mixed field's extents shard their batch axis like the field
+            base = self._spec_for(field)
+            return PartitionSpec(base[0] if len(base) else None)
         if isinstance(self._specs, dict):
             spec = self._specs.get(name)
         else:
@@ -814,6 +948,8 @@ class JaxDataLoader:
                     break
                 if out.num_rows < local_bs and self._drop_last:
                     continue  # partial tail batch dropped
+                if self._extent_keys:
+                    out = self._pack_mixed(out)
                 self._host_push(out)
             self._host_push(_Done())
         except BaseException as exc:  # noqa: BLE001 - forwarded downstream
@@ -919,6 +1055,8 @@ class JaxDataLoader:
         cols = {n: host_batch.columns[n] for n in self._fields
                 if n not in self._device_decode
                 or (n in self._split_decode and n in host_batch.columns)}
+        for name, key in self._extent_keys.items():
+            cols[key] = host_batch.columns[_mixed_key(name)].extents
         if self._transform_fn is not None:
             cols = self._transform_fn(cols)
             if self._valid_mask is not None and self._valid_mask in cols:
@@ -1261,26 +1399,14 @@ class JaxDataLoader:
     def _decode_mixed_stack(self, name: str, group
                             ) -> Tuple[jax.Array, List[jax.Array]]:
         """Stack-mode variant of ``_decode_mixed_on_device``: the K batches'
-        cells decode as one flat ``K*local``-row bucket pass (host-local, as
-        ever), then reshape to ``(K, local, ...)`` and scatter along the
+        canvas groups finish into one flat ``K*local``-row batch (host-local,
+        as ever), then reshape to ``(K, local, ...)`` and scatter along the
         batch axis.  Returns the decoded field and the arrays copied from
         the host."""
-        import jax.numpy as jnp
-
-        from petastorm_tpu.native.image import (COEF_COLUMN_SEP,
-                                                MIXED_CELL_SUFFIX)
-
         K, local = self._stack, self._local_rows
-        key = f"{name}{COEF_COLUMN_SEP}{MIXED_CELL_SUFFIX}"
-        flat = np.concatenate([hb.columns[key] for hb in group])
-        n = len(flat)   # real cells form a prefix: only the LAST batch is short
-        out, sent = self._decode_mixed_flat(name, flat, K * local)
-        field = self._schema[name]
-        if len(field.shape) == 3 and field.shape[2] == 1 and out.ndim == 3:
-            out = out[..., None]
-        if n < K * local:
-            out = jnp.concatenate(
-                [out, jnp.zeros((K * local - n,) + out.shape[1:], out.dtype)])
+        packs = [(k * local, hb.columns[_mixed_key(name)])
+                 for k, hb in enumerate(group)]
+        out, sent = self._decode_mixed_flat(name, packs, K * local)
         out = out.reshape((K, local) + out.shape[1:])
         if self._mesh is not None:
             out = self._scatter_stacked_rows(name, out)
@@ -1295,120 +1421,158 @@ class JaxDataLoader:
     def _decode_mixed_on_device(self, name: str, columns: Dict[str, np.ndarray]
                                 ) -> Tuple[jax.Array, List[jax.Array]]:
         """Finish the hybrid decode of a MIXED-geometry field
-        (decode_placement='device-mixed').
-
-        The batch's object cells are re-grouped by jpeg geometry; each
-        geometry bucket's planes are padded to a power-of-two size (never a
-        data-dependent one - compiles stay bounded by geometries x
-        log2(batch), see ``_decode_mixed_flat``), decoded, fitted
-        (pad/crop) to the static target, then scattered back into batch
-        order.  The wasted FLOPs on the padding rows are cheap: the on-chip
-        half is small next to the host entropy decode.  Returns the decoded
-        field and the arrays copied from the host.
-        """
-        from petastorm_tpu.native.image import (COEF_COLUMN_SEP,
-                                                MIXED_CELL_SUFFIX)
-
-        field = self._schema[name]
-        col = columns[f"{name}{COEF_COLUMN_SEP}{MIXED_CELL_SUFFIX}"]
-        n = len(col)
-        out, sent = self._decode_mixed_flat(name, col,
+        (decode_placement='device-mixed') from the canvas groups the
+        assembly thread packed (``_pack_mixed``), then scatter the rows over
+        the mesh.  Returns the decoded field and the arrays copied from the
+        host."""
+        pack = columns[_mixed_key(name)]
+        n = len(pack)
+        out, sent = self._decode_mixed_flat(name, [(0, pack)],
                                             max(self._local_rows, n))
-        if len(field.shape) == 3 and field.shape[2] == 1 and out.ndim == 3:
-            out = out[..., None]
         if self._mesh is not None:
             out = self._scatter_local_rows(name, out, n)
         return out, sent
 
-    def _decode_mixed_flat(self, name: str, col, batch_pad: int
+    def _decode_mixed_flat(self, name: str, packs, n_out: int
                            ) -> Tuple[jax.Array, List[jax.Array]]:
-        """Bucket-decode one flat column of mixed-geometry cells.  Each
-        bucket pads its group to the next power of two (min 8, capped at
-        ``batch_pad``) - NOT to the full batch: padding every bucket to
-        ``batch_pad`` made a G-geometry batch decode and transfer G x the
-        data, which measurably handed the hybrid-decode win back to the
-        host path (bench ``imagenet_ingest_mixed_samples_per_sec``).
-        Power-of-two sizes keep every op static-shaped with compiles
-        bounded by geometries x log2(batch) (decode/fit) plus the distinct
-        per-batch size compositions (concat/gather).  Returns
-        ``(len(col), *target)`` rows in column order, on the default device
-        (the decode is host-local; mesh placement happens after), and the
-        arrays copied from the host: each bucket's planes and tables, and
-        the gather index."""
+        """Finish ``packs`` (``(row offset, _MixedPack)`` pairs) into one
+        ``(n_out, *target)`` uint8 batch on the default device: one on-chip
+        call per canvas group, each writing its images into their rows (the
+        decode is host-local; mesh placement happens after).  Returns the
+        batch and the arrays copied from the host: each group's planes,
+        tables, extents and rows, copied in one ``device_put``."""
         import jax.numpy as jnp
 
-        from petastorm_tpu.native.image import _layout_from_meta
-        from petastorm_tpu.ops.jpeg import decode_coefficients
-
         target = self._mixed_target(name)
-        n = len(col)
-        groups: Dict[bytes, list] = {}
-        for i, cell in enumerate(col):
-            groups.setdefault(cell[2].tobytes(), []).append(i)
-        self._mixed_geometries.setdefault(name, set()).update(groups)
-        parts = []
+        groups, host = [], []
+        for offset, pack in packs:
+            for g in pack.groups:
+                rows = np.where(g.rows < 0, n_out, g.rows + offset)
+                groups.append(g)
+                host.append((g.planes, g.qtabs, g.extents,
+                             rows.astype(np.int32)))
+        out = jnp.zeros((n_out,) + target, jnp.uint8)
         sent: List[jax.Array] = []
-        flat_idx = np.empty(n, dtype=np.int64)
-        offset = 0
-        for key, idxs in groups.items():
-            layout = _layout_from_meta(np.frombuffer(key, dtype=np.int32))
-            self._check_declared_geometry(name, layout)
-            k = len(idxs)
-            pad_k = min(max(8, 1 << (k - 1).bit_length()), batch_pad)
-            planes = []
-            for c in range(len(layout.components)):
-                stack = np.stack([col[i][0][c] for i in idxs])
-                if k < pad_k:
-                    stack = np.concatenate(
-                        [stack, np.zeros((pad_k - k,) + stack.shape[1:],
-                                         stack.dtype)])
-                planes.append(stack)
-            qtabs = np.stack([col[i][1] for i in idxs])
-            if k < pad_k:
-                qtabs = np.concatenate(
-                    [qtabs, np.ones((pad_k - k,) + qtabs.shape[1:],
-                                    qtabs.dtype)])
-            sampling = tuple((h, v) for (h, v, _, _) in layout.components)
-            jp, jq = jax.device_put((tuple(planes), qtabs))
-            sent += [*jp, jq]
-            img = decode_coefficients(jp, jq,
-                                      image_size=(layout.height, layout.width),
-                                      sampling=sampling)
-            if len(target) == 3:
-                if img.ndim == 3:
-                    img = img[..., None]
-                if img.shape[-1] != target[2]:
-                    if img.shape[-1] == 1:
-                        img = jnp.repeat(img, target[2], axis=-1)
-                    else:
-                        raise PetastormTpuError(
-                            f"field {name!r}: a stored jpeg decodes to"
-                            f" {img.shape[-1]}-channel images but the target"
-                            f" {target} wants {target[2]} channel(s); declare"
-                            " a (H, W, 3) shape/target or store grayscale"
-                            " jpegs")
-            elif img.ndim == 4:
+        for g, (jp, jq, je, jr) in zip(groups, jax.device_put(host)):
+            out = self._finish_program(name, g, n_out)(out, jp, jq, je, jr)
+            sent += [*jp, jq, je, jr]
+        return out, sent
+
+    def _mixed_group_sizes(self) -> Tuple[int, ...]:
+        return tuple(sorted({min(s, self._local_rows)
+                             for s in _MIXED_GROUP_SIZES}))
+
+    def _finish_program(self, name: str, group: "_CanvasGroup", n_out: int):
+        """The compiled on-chip finish of ``group``'s (chroma layout,
+        canvas, group size).  A layout's first group compiles the programs
+        of EVERY canvas and group size of the field, so nothing compiles
+        after the batch where a layout first shows: the count
+        (``loader.mixed_finish_programs``) is layouts x canvases x group
+        sizes, whatever the geometries."""
+        from petastorm_tpu.ops.jpeg import finish_canvas
+
+        key = (name, group.sampling, group.canvas, len(group.rows))
+        program = self._finish_programs.get(key)
+        if program is not None:
+            return program
+        target = self._mixed_target(name)
+        sds = jax.ShapeDtypeStruct
+        for canvas in self._mixed_canvases(name):
+            for size in self._mixed_group_sizes():
+                planes = tuple(
+                    sds((size,) + shape + (64,), np.int16) for shape
+                    in _canvas_plane_shapes(canvas, group.sampling))
+                self._finish_programs[(name, group.sampling, canvas, size)] = (
+                    finish_canvas.lower(
+                        sds((n_out,) + target, np.uint8), planes,
+                        sds((size, len(planes), 64), np.uint16),
+                        sds((size, 2), np.int32), sds((size,), np.int32),
+                        canvas_hw=canvas, sampling=group.sampling).compile())
+                self._m_mixed_programs.add(1)
+        return self._finish_programs[key]
+
+    def _pack_mixed(self, batch: ColumnBatch) -> ColumnBatch:
+        """The 'mixed-pack' stage (assembly thread): each 'device-mixed'
+        field's object cells become canvas groups (``_pack_canvases``)."""
+        tele = self._telemetry
+        cols = dict(batch.columns)
+        with (tele.stage("mixed-pack", rows=batch.num_rows)
+              if tele.enabled else _NULL_CONTEXT):
+            for name in self._extent_keys:
+                key = _mixed_key(name)
+                cols[key] = self._pack_canvases(name, cols[key])
+        return dataclasses.replace(batch, columns=cols)
+
+    def _pack_canvases(self, name: str, col) -> "_MixedPack":
+        """Pad each image's coefficient planes top-left into the smallest
+        canvas that holds it.  Per chroma layout and canvas, the images go
+        in groups of the largest of ``_MIXED_GROUP_SIZES``, the rest in the
+        smallest size that holds them, padded with rows the finish drops."""
+        from petastorm_tpu.native.image import _layout_from_meta
+
+        n = len(col)
+        keys = [cell[2].tobytes() for cell in col]
+        seen = self._mixed_geometries.setdefault(name, {})
+        for key in set(keys) - seen.keys():
+            seen[key] = _layout_from_meta(np.frombuffer(key, dtype=np.int32))
+            self._check_declared_geometry(name, seen[key])
+        layouts = [seen[k] for k in keys]
+        extents = np.asarray([(lay.height, lay.width) for lay in layouts],
+                             np.int32).reshape(n, 2)
+        canvases = self._mixed_canvases(name)
+        ch = np.asarray([c[0] for c in canvases])
+        cw = np.asarray([c[1] for c in canvases])
+        fits = ((extents[:, :1] <= ch[None]) & (extents[:, 1:] <= cw[None]))
+        if not fits[:, -1].all():
+            i = int(np.argmin(fits[:, -1]))
+            raise PetastormTpuError(
+                f"field {name!r}: a stored jpeg of {tuple(extents[i])}"
+                f" (height, width) does not fit the largest canvas"
+                f" {canvases[-1]}; add a larger pad_shapes canvas")
+        canvas_of = fits.argmax(axis=1)   # canvases run smallest first
+        target = self._mixed_target(name)
+        by_sampling: Dict[tuple, List[int]] = {}
+        for i, lay in enumerate(layouts):
+            ncomp = len(lay.components)
+            if ncomp not in (1, 3) or (ncomp == 3 and (
+                    len(target) == 2 or target[2] != 3)):
                 raise PetastormTpuError(
-                    f"field {name!r}: stored jpeg decodes to"
-                    f" {img.shape[-1]}-channel images but the target {target}"
-                    " is 2-D; declare a (H, W, C) shape/target")
-            # fit to the static target: crop the excess, zero-pad the rest
-            img = img[:, :min(img.shape[1], target[0]),
-                      :min(img.shape[2], target[1])]
-            pad = [(0, 0), (0, target[0] - img.shape[1]),
-                   (0, target[1] - img.shape[2])]
-            if img.ndim == 4:
-                pad.append((0, 0))
-            parts.append(jnp.pad(img, pad))        # (pad_k, *target)
-            flat_idx[np.asarray(idxs)] = offset + np.arange(k)
-            offset += pad_k
-        stacked = (jnp.concatenate(parts, axis=0) if len(parts) > 1
-                   else parts[0])
-        # one static-shape gather scatters rows back into batch order and
-        # drops the pad rows in the same pass
-        idx = jax.device_put(flat_idx)
-        sent.append(idx)
-        return stacked[idx], sent
+                    f"field {name!r}: a stored jpeg has {ncomp} colour"
+                    f" components, which the delivered shape {target}"
+                    " cannot hold; store grayscale or RGB jpegs and declare"
+                    " (H, W) or (H, W, 3)")
+            sampling = tuple((h, v) for (h, v, _, _) in lay.components)
+            by_sampling.setdefault(sampling, []).append(i)
+        sizes = self._mixed_group_sizes()
+        groups = []
+        canvas_px = 0
+        for sampling, idx in by_sampling.items():
+            idx = np.asarray(idx)
+            for c, canvas in enumerate(canvases):
+                members = idx[canvas_of[idx] == c]
+                for a in range(0, len(members), sizes[-1]):
+                    part = members[a:a + sizes[-1]]
+                    size = next(s for s in sizes if s >= len(part))
+                    groups.append(_CanvasGroup.pack(col, part, extents, canvas,
+                                                    sampling, size))
+                canvas_px += len(members) * canvas[0] * canvas[1]
+        self._m_mixed_valid.add(float(np.prod(extents, axis=1).sum()))
+        self._m_mixed_canvas.add(float(canvas_px))
+        return _MixedPack(extents, groups)
+
+    def _check_canvases_hold_declared(self, name: str) -> None:
+        """Construction-time check of a 'device-mixed' field: it has
+        canvases, and the largest holds every geometry the dataset's
+        stamped contract declares."""
+        largest = self._mixed_canvases(name)[-1]
+        too_big = sorted({(int(s[0]), int(s[1]))
+                          for s in self._declared_geometries.get(name, ())
+                          if s[0] > largest[0] or s[1] > largest[1]})
+        if too_big:
+            raise PetastormTpuError(
+                f"field {name!r}: the dataset declares image geometries"
+                f" {too_big[:5]} (height, width) larger than the largest"
+                f" canvas {largest}; add a pad_shapes canvas that holds them")
 
     def _check_declared_geometry(self, name: str, layout) -> None:
         """Warn (once per geometry) when a batch reveals an image geometry
@@ -1601,11 +1765,12 @@ class JaxDataLoader:
         if self._stack > 1:
             out["stack_batches"] = self._stack
         if self._mixed_geometries:
-            # distinct jpeg geometries decoded per 'device-mixed' field: the
-            # on-chip decode compiles once per entry PER power-of-two group
-            # size (bounded: geometries x log2(batch), _decode_mixed_flat)
+            # distinct jpeg geometries per 'device-mixed' field, and the
+            # on-chip finish programs compiled for them: chroma layouts x
+            # canvases x group sizes, however many geometries
             out["mixed_decode_geometries"] = {
                 name: len(keys) for name, keys in self._mixed_geometries.items()}
+            out["mixed_finish_programs"] = len(self._finish_programs)
             if self._declared_geometries:
                 # the dataset-level bound those counts must stay under
                 out["declared_geometries"] = {
@@ -1839,7 +2004,8 @@ class JaxDataLoader:
         # fields relative to self._fields
         staged = (list(self._emitted_layout)
                   + [n for n in self._device_decode if n in self._fields]
-                  if self._emitted_layout else list(self._fields))
+                  if self._emitted_layout
+                  else list(self._fields) + list(self._extent_field))
         if self._valid_mask is not None and self._valid_mask not in staged:
             staged.append(self._valid_mask)
         for name in staged:
@@ -1857,9 +2023,13 @@ class JaxDataLoader:
                 trailing = ()
                 sharding = NamedSharding(self._mesh, self._delivery_spec(name))
                 dtype = np.float32
+            elif name in self._extent_field:
+                trailing = (2,)
+                sharding = NamedSharding(self._mesh, self._delivery_spec(name))
+                dtype = np.int32
             elif name in self._device_decode:
                 # mixed fields may declare a variable shape; their static
-                # delivery shape is the fit target, not the schema shape
+                # delivery shape is the largest canvas, not the schema shape
                 trailing = (self._mixed_target(name)
                             if name in self._mixed_decode
                             else tuple(field.shape))

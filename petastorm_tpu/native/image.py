@@ -371,8 +371,8 @@ def pack_coef_columns(name: str, column, field=None, nthreads: int = 1) -> dict:
 _MIXED_GEOMETRY_GUIDANCE = (
     "decode_placement='device' requires every stored jpeg to share one"
     " geometry and subsampling (XLA compiles the on-chip decode per geometry)."
-    " Use decode_placement='device-mixed' (per-geometry bucketed on-chip"
-    " decode), re-encode uniformly (petastorm-tpu-copy-dataset --jpeg-quality),"
+    " Use decode_placement='device-mixed' (on-chip decode in fixed"
+    " canvases), re-encode uniformly (petastorm-tpu-copy-dataset --jpeg-quality),"
     " or use decode_placement='host'")
 
 
@@ -418,10 +418,10 @@ def pack_coef_columns_mixed(name: str, column, field=None,
     Worker side of ``decode_placement='device-mixed'``: rows are grouped by
     coefficient-plane geometry (header parse only), each group entropy-decodes
     through the batched GIL-released C call, and every row becomes one object
-    cell ``(planes, qtab, meta)``.  The jax loader re-groups the assembled
-    batch by geometry and runs the on-chip half once per geometry bucket
-    (petastorm_tpu/ops/jpeg.py), so XLA compiles are bounded by the number of
-    distinct geometries in the dataset.
+    cell ``(planes, qtab, meta)``.  The jax loader pads the assembled
+    batch's planes into fixed canvases and runs the on-chip half once per
+    canvas group (petastorm_tpu/ops/jpeg.py ``finish_canvas``), so XLA
+    compiles are bounded by chroma layouts x canvases x group sizes.
 
     A fixed-shape schema field must match every stored geometry; declare
     wildcard dims (e.g. ``(None, None, 3)``) for genuinely mixed datasets.

@@ -8,7 +8,7 @@ normalization happens on-chip"), keeping the host->device transfer at 1 byte/pix
 (4x less PCIe/DCN traffic than shipping float32).
 """
 
-from petastorm_tpu.ops.augment import (cutmix, mixup, random_crop,
+from petastorm_tpu.ops.augment import (crop_boxes, cutmix, mixup, random_crop,
                                        random_crop_flip, random_flip,
                                        random_resized_crop, resize_images)
 from petastorm_tpu.ops.normalize import normalize_images
@@ -20,4 +20,5 @@ from petastorm_tpu.ops.ulysses import (ulysses_attention,
 __all__ = ["normalize_images", "ring_attention", "ring_attention_sharded",
            "ulysses_attention", "ulysses_attention_sharded",
            "random_crop", "random_flip", "random_crop_flip",
-           "random_resized_crop", "resize_images", "mixup", "cutmix"]
+           "random_resized_crop", "crop_boxes", "resize_images", "mixup",
+           "cutmix"]

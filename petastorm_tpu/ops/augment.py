@@ -19,6 +19,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 @functools.partial(jax.jit, static_argnames=("crop_hw",))
@@ -135,6 +136,97 @@ def resize_images(images: jax.Array, out_hw: Tuple[int, int],
     return _restore_dtype(out, images.dtype)
 
 
+#: candidate boxes ``crop_boxes`` draws per image before it falls back to
+#: the centre crop (torchvision's RandomResizedCrop tries 10)
+_CROP_TRIES = 10
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "ratio"))
+def crop_boxes(key: jax.Array, extents: jax.Array,
+               scale: Tuple[float, float] = (0.08, 1.0),
+               ratio: Tuple[float, float] = (3.0 / 4.0, 4.0 / 3.0)
+               ) -> jax.Array:
+    """torchvision's ``RandomResizedCrop.get_params``, per image and
+    branch-free: int32 ``(N, 4)`` boxes ``(top, left, height, width)``
+    inside each image's own ``extents`` (int ``(N, 2)``: height, width).
+
+    Ten candidate boxes per image, each of an area drawn in ``scale`` of
+    the image's and an aspect (width / height) log-uniform in ``ratio``,
+    sides rounded to whole pixels; the first that fits is placed uniformly
+    at random.  When none fits, the centre crop with the aspect clamped to
+    ``ratio``.  ``random_resized_crop(..., extents=...)`` crops these boxes
+    with the same ``key``."""
+    n = extents.shape[0]
+    h = extents[:, 0].astype(jnp.float32)
+    w = extents[:, 1].astype(jnp.float32)
+    k_area, k_ratio, k_y, k_x = jax.random.split(key, 4)
+    area = (h * w)[:, None] * jax.random.uniform(
+        k_area, (n, _CROP_TRIES), minval=scale[0], maxval=scale[1])
+    aspect = jnp.exp(jax.random.uniform(
+        k_ratio, (n, _CROP_TRIES), minval=jnp.log(ratio[0]),
+        maxval=jnp.log(ratio[1])))
+    cw = jnp.round(jnp.sqrt(area * aspect))
+    ch = jnp.round(jnp.sqrt(area / aspect))
+    fits = (cw > 0) & (cw <= w[:, None]) & (ch > 0) & (ch <= h[:, None])
+    first = jnp.argmax(fits, axis=1)[:, None]
+    found = fits.any(axis=1)
+    cw = jnp.take_along_axis(cw, first, axis=1)[:, 0]
+    ch = jnp.take_along_axis(ch, first, axis=1)[:, 0]
+    in_ratio = w / h
+    fw = jnp.where(in_ratio > ratio[1], jnp.round(h * ratio[1]), w)
+    fh = jnp.where(in_ratio < ratio[0], jnp.round(w / ratio[0]), h)
+    bw = jnp.where(found, cw, fw).astype(jnp.int32)
+    bh = jnp.where(found, ch, fh).astype(jnp.int32)
+    hi, wi = extents[:, 0].astype(jnp.int32), extents[:, 1].astype(jnp.int32)
+    top = jnp.where(found, jax.random.randint(k_y, (n,), 0, hi - bh + 1),
+                    (hi - bh) // 2)
+    left = jnp.where(found, jax.random.randint(k_x, (n,), 0, wi - bw + 1),
+                     (wi - bw) // 2)
+    return jnp.stack([top, left, bh, bw], axis=1)
+
+
+def _taps(start: jax.Array, size: jax.Array, out_len: int):
+    """Bilinear source taps of ``out_len`` output samples over each box
+    ``[start, start + size)`` (``(N,)`` each), as PyTorch's bilinear
+    ``interpolate`` (half-pixel centres, no antialias) takes them over the
+    cropped box: ``(i0, i1, weight of i1)``, each ``(N, out_len)``, with
+    both taps clamped to the box, so no sample reads outside it."""
+    size = jnp.maximum(size, 1)
+    # the scale as size times the float32 reciprocal, so that the compiler
+    # has no division by a constant left to rewrite: a plain float32
+    # reference then takes the very same taps
+    scale = size.astype(jnp.float32) * np.float32(1.0 / out_len)
+    centres = np.arange(out_len, dtype=np.float32) + np.float32(0.5)
+    src = jnp.maximum(scale[:, None] * centres[None, :] - np.float32(0.5),
+                      0.0)
+    i0 = src.astype(jnp.int32)
+    lam = src - i0.astype(jnp.float32)
+    i1 = jnp.minimum(i0 + 1, size[:, None] - 1)
+    return start[:, None] + i0, start[:, None] + i1, lam
+
+
+def _crop_in_extents(images: jax.Array, boxes: jax.Array,
+                     out_hw: Tuple[int, int]) -> jax.Array:
+    """Bilinear resample of each ``(top, left, height, width)`` box of
+    ``images`` to ``out_hw``, in float32: rows first, then columns."""
+    oh, ow = out_hw
+    y0, y1, ly = _taps(boxes[:, 0], boxes[:, 2], oh)
+    x0, x1, lx = _taps(boxes[:, 1], boxes[:, 3], ow)
+
+    def rows(idx):
+        return jnp.take_along_axis(images, idx[:, :, None, None],
+                                   axis=1).astype(jnp.float32)
+
+    ly = ly[:, :, None, None]
+    r = rows(y0) * (1.0 - ly) + rows(y1) * ly
+
+    def cols(idx):
+        return jnp.take_along_axis(r, idx[:, None, :, None], axis=2)
+
+    lx = lx[:, None, :, None]
+    return cols(x0) * (1.0 - lx) + cols(x1) * lx
+
+
 @functools.partial(jax.jit,
                    static_argnames=("out_hw", "scale", "ratio", "method",
                                     "antialias"))
@@ -143,8 +235,17 @@ def random_resized_crop(images: jax.Array, key: jax.Array,
                         scale: Tuple[float, float] = (0.08, 1.0),
                         ratio: Tuple[float, float] = (3.0 / 4.0, 4.0 / 3.0),
                         method: str = "bilinear",
-                        antialias: bool = False) -> jax.Array:
+                        antialias: bool = False,
+                        extents: Optional[jax.Array] = None) -> jax.Array:
     """torchvision-style RandomResizedCrop, fully on-chip and batched.
+
+    With ``extents`` (int ``(N, 2)``: each image's own height and width,
+    as a 'device-mixed' loader delivers them beside a batch of canvases),
+    each box is drawn inside its image's extent exactly as torchvision
+    draws it (``crop_boxes``, same ``key``) and resampled bilinearly as
+    torchvision's tensor ``resized_crop`` does without antialias, with
+    every tap clamped to the box: nothing outside the image is read.
+    Only ``method='bilinear'`` without ``antialias`` is offered there.
 
     Per image: sample a crop area fraction in ``scale`` and an aspect ratio
     log-uniform in ``ratio``, place the crop uniformly, and resize it to
@@ -163,6 +264,13 @@ def random_resized_crop(images: jax.Array, key: jax.Array,
     constant-folds at trace time: one such configuration measured 149 ms for
     the same batch - keep the scale a traced value if you fork this.
     """
+    if extents is not None:
+        if method != "bilinear" or antialias:
+            raise ValueError("random_resized_crop with extents resamples"
+                             " bilinearly without antialias only")
+        boxes = crop_boxes(key, extents, scale, ratio)
+        return _restore_dtype(_crop_in_extents(images, boxes, out_hw),
+                              images.dtype)
     n, h, w, c = images.shape
     oh, ow = out_hw
     k_area, k_ratio, k_y, k_x = jax.random.split(key, 4)

@@ -84,6 +84,29 @@ def _upsample_axis_fancy(x: jax.Array, axis: int) -> jax.Array:
     return jnp.moveaxis(out, -1, axis)
 
 
+def _edge_next(x: jax.Array, nxt: jax.Array, at_edge: jax.Array) -> jax.Array:
+    """The right-hand (or lower) neighbour the fancy filter reads: the
+    sample itself at the image's own edge (libjpeg replicates there)."""
+    return jnp.where(at_edge, x, nxt)
+
+
+def _upsample_axis_fancy_within(x: jax.Array, axis: int,
+                                extent: jax.Array) -> jax.Array:
+    """``_upsample_axis_fancy`` over a canvas whose first ``extent`` samples
+    (per image, ``(N,)`` along the leading axis) are the image's own: the
+    filter replicates at each image's last sample, not at the canvas edge,
+    so inside the extent it gives what the per-geometry decode gives."""
+    x = jnp.moveaxis(x, axis, -1)
+    prev = jnp.concatenate([x[..., :1], x[..., :-1]], axis=-1)
+    nxt = jnp.concatenate([x[..., 1:], x[..., -1:]], axis=-1)
+    last = (extent - 1).reshape(extent.shape + (1,) * (x.ndim - 1))
+    nxt = _edge_next(x, nxt, jnp.arange(x.shape[-1]) >= last)
+    even = (3.0 * x + prev) * 0.25
+    odd = (3.0 * x + nxt) * 0.25
+    out = jnp.stack([even, odd], axis=-1).reshape(*x.shape[:-1], -1)
+    return jnp.moveaxis(out, -1, axis)
+
+
 def _upsample_to(plane: jax.Array, factors: Tuple[int, int], height: int,
                  width: int, fancy: bool) -> jax.Array:
     """Upsample (..., ch, cw) by integer ``factors`` and crop to (h, w)."""
@@ -154,6 +177,76 @@ def decode_coefficients(planes: Sequence[jax.Array],
     if jnp.issubdtype(jnp.dtype(out_dtype), jnp.integer):
         out = jnp.clip(jnp.round(out), 0, 255)
     return out.astype(out_dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("canvas_hw", "sampling",
+                                             "fancy_upsampling"),
+                   donate_argnums=(0,))
+def finish_canvas(out: jax.Array, planes: Sequence[jax.Array],
+                  qtabs: jax.Array, extents: jax.Array, rows: jax.Array,
+                  canvas_hw: Tuple[int, int],
+                  sampling: Tuple[Tuple[int, int], ...],
+                  fancy_upsampling: bool = True) -> jax.Array:
+    """Finish a group of images of any geometry, each padded top-left into
+    one canvas, and write them into rows of ``out``.
+
+    The canvas path of ``decode_placement='device-mixed'``: one compiled
+    program per (chroma layout, canvas, group size), however many image
+    geometries the data holds.
+
+    Args:
+      out: uint8 ``(B, H, W[, C])`` batch being filled, donated; ``H, W`` at
+        least ``canvas_hw``.
+      planes: per component, int16 ``(G, bh, bw, 64)`` coefficient planes of
+        the canvas (MCU-aligned), each image's blocks top-left, zeros after.
+      qtabs: uint16 ``(G, ncomp, 64)`` quant tables.
+      extents: int32 ``(G, 2)``: each image's own (height, width).
+      rows: int32 ``(G,)``: the row of ``out`` each image goes to; a row
+        outside ``out`` (the group's padding) is dropped.
+      canvas_hw: (height, width) of the canvas region written.
+      sampling: per component (h_samp, v_samp).
+
+    Inside each image's extent the pixels are those ``decode_coefficients``
+    gives for the image's own geometry: fancy upsampling replicates at the
+    image's own right and bottom edge.  Outside it they are 0.
+    """
+    ncomp = len(planes)
+    if ncomp not in (1, 3):
+        raise ValueError(f"unsupported component count {ncomp}")
+    max_h = max(s[0] for s in sampling)
+    max_v = max(s[1] for s in sampling)
+    height, width = extents[:, 0], extents[:, 1]
+    comps = []
+    for c, coefs in enumerate(planes):
+        plane = _idct_blocks(coefs, qtabs[..., c, :])
+        h_samp, v_samp = sampling[c]
+        for axis, f, ext in ((-2, max_v // v_samp, -(-height * v_samp // max_v)),
+                             (-1, max_h // h_samp, -(-width * h_samp // max_h))):
+            if f == 1:
+                continue
+            if fancy_upsampling and f == 2:
+                plane = _upsample_axis_fancy_within(plane, axis, ext)
+            else:
+                plane = jnp.repeat(plane, f, axis=axis)
+        comps.append(plane)
+    ch, cw = canvas_hw
+    comps = [p[:, :ch, :cw] for p in comps]
+    if ncomp == 1:
+        img = comps[0]
+    else:
+        ycc = jnp.stack(comps, axis=-1)
+        ycc = ycc - jnp.asarray([0.0, 128.0, 128.0], dtype=jnp.float32)
+        img = jnp.matmul(ycc, jnp.asarray(_YCC_TO_RGB).T,
+                         precision=jax.lax.Precision.HIGHEST)
+    img = jnp.clip(jnp.round(img), 0, 255).astype(jnp.uint8)
+    inside = ((jnp.arange(ch)[None, :, None] < height[:, None, None])
+              & (jnp.arange(cw)[None, None, :] < width[:, None, None]))
+    if img.ndim == 4:
+        inside = inside[..., None]
+    img = jnp.where(inside, img, jnp.uint8(0))
+    if out.ndim == 4 and img.ndim == 3:
+        img = jnp.repeat(img[..., None], out.shape[-1], axis=-1)
+    return out.at[rows, :ch, :cw].set(img, mode="drop")
 
 
 def decode_from_layout(planes, qtabs, layout, out_dtype=jnp.uint8,

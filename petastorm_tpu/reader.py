@@ -126,8 +126,11 @@ def make_reader(dataset_url: str,
     iteration and the torch/tf adapters refuse such readers (they would see
     planes, not pixels).  ``'device'`` requires uniform jpeg geometry across
     the dataset (one XLA compile); ``'device-mixed'`` supports mixed
-    geometries/subsamplings via per-geometry bucketed decode (compiles
-    bounded by the number of distinct geometries; single-device loaders).
+    geometries/subsamplings: the loader pads each image into the smallest
+    of a fixed set of canvases (its ``pad_shapes``) and delivers each
+    image's extent beside the batch, so compiles are bounded by chroma
+    layouts x canvases x three group sizes, never by the number of
+    distinct geometries.
 
     ``decode_placement={'field': 'auto'}`` makes the host<->device split a
     LIVE knob (docs/operations.md "Decode tuning"): workers consult a shared
@@ -1062,9 +1065,9 @@ def _validate_decode_placement(decode_placement, schema, read_fields,
     ``'device'`` is the uniform-geometry fast path: fixed-shape plane columns
     (batch/shuffle/shm as ordinary arrays), one XLA compile for the whole
     dataset.  ``'device-mixed'`` supports datasets mixing jpeg geometries/
-    subsamplings: rows travel as object cells and the loader decodes each
-    geometry bucket on-chip separately (compiles bounded by the number of
-    distinct geometries; see JaxDataLoader for the pad-target contract).
+    subsamplings: rows travel as object cells and the loader finishes them
+    on-chip in fixed canvases (compiles bounded by chroma layouts x
+    canvases x group sizes; see JaxDataLoader for the canvas contract).
     """
     if not decode_placement:
         return [], frozenset(), frozenset()

@@ -1,10 +1,10 @@
 """decode_placement='device-mixed': on-chip decode of MIXED jpeg geometries.
 
-Lifts the uniform-geometry restriction of the 'device' fast path: rows are
-grouped by (H, W, subsampling), each geometry bucket decodes on-chip with its
-planes padded to the full batch size - so XLA compiles the decode exactly once
-per geometry, never per data-dependent group size - then every image is
-padded/cropped to one static target.
+Lifts the uniform-geometry restriction of the 'device' fast path: the host
+pads each image's coefficient planes into the smallest of a fixed set of
+canvases, and the chip finishes groups of images one canvas at a time - so
+XLA compiles the finish once per (chroma layout, canvas), however many
+geometries the data holds - into one static delivered shape.
 
 Reference analog: the host decode handles any geometry per cell
 (petastorm/codecs.py:92-118); this gets the same generality on the device
@@ -52,21 +52,11 @@ def mixed_ds(tmp_path_factory):
     return url
 
 
-def test_mixed_decode_matches_host_decode(mixed_ds, monkeypatch):
+def test_mixed_decode_matches_host_decode(mixed_ds):
     """Every geometry decodes on-device to within the hybrid-decode pixel
     contract of its host decode, padded to the static target - and the
-    on-chip decode sees a BOUNDED set of shapes (one per geometry)."""
-    import petastorm_tpu.ops.jpeg as ops_jpeg
-
-    signatures = set()
-    real = ops_jpeg.decode_coefficients
-
-    def recording(planes, qtabs, image_size, sampling, **kw):
-        signatures.add((tuple(p.shape for p in planes), image_size, sampling))
-        return real(planes, qtabs, image_size=image_size, sampling=sampling, **kw)
-
-    monkeypatch.setattr(ops_jpeg, "decode_coefficients", recording)
-
+    on-chip finish compiles a BOUNDED set of programs (one per canvas and
+    chroma layout, not one per geometry)."""
     with make_batch_reader(mixed_ds, shuffle_row_groups=False, num_epochs=2,
                            decode_placement={"image": "device-mixed"}) as r:
         assert r.device_decode_mixed == frozenset({"image"})
@@ -82,9 +72,9 @@ def test_mixed_decode_matches_host_decode(mixed_ds, monkeypatch):
     assert sorted(got) == list(range(N_ROWS))
     assert all(len(v) == 2 for v in got.values())  # both epochs delivered
 
-    # bounded compiles: one decode signature per geometry, across 2 epochs
-    # and 6 batches (data-dependent group sizes are padded away)
-    assert len(signatures) == len(GEOMETRIES)
+    # bounded compiles: one finish program for the one canvas and 4:2:0
+    # layout, across 3 geometries, 2 epochs and 6 batches
+    assert diag["mixed_finish_programs"] == 1
     assert diag["mixed_decode_geometries"] == {"image": len(GEOMETRIES)}
 
     for i in range(N_ROWS):
@@ -140,13 +130,20 @@ def test_mixed_subsampling_within_one_size(tmp_path):
 
 
 def test_mixed_requires_static_target(mixed_ds):
+    """A variable-shape mixed field needs canvases; the largest is the
+    static delivered shape, so it must hold every other canvas and every
+    geometry the dataset declares."""
     with make_batch_reader(mixed_ds, num_epochs=1,
                            decode_placement={"image": "device-mixed"}) as r:
-        with pytest.raises(PetastormTpuError, match="ONE pad_shapes target"):
+        with pytest.raises(PetastormTpuError, match="pad_shapes canvases"):
             JaxDataLoader(r, batch_size=8, fields=["idx", "image"])
-        with pytest.raises(PetastormTpuError, match="ONE pad_shapes target"):
+        with pytest.raises(PetastormTpuError, match="hold every other"):
             JaxDataLoader(r, batch_size=8, fields=["idx", "image"],
-                          pad_shapes={"image": [(32, 32, 3), (64, 96, 3)]})
+                          pad_shapes={"image": [(32, 96, 3), (64, 64, 3)]})
+        with pytest.raises(PetastormTpuError,
+                           match="larger than the largest canvas"):
+            JaxDataLoader(r, batch_size=8, fields=["idx", "image"],
+                          pad_shapes={"image": [(32, 32, 3), (48, 64, 3)]})
 
 
 def test_declared_geometries_stamped_at_write(mixed_ds):
@@ -159,24 +156,13 @@ def test_declared_geometries_stamped_at_write(mixed_ds):
         (h, w, 3) for h, w in GEOMETRIES)
 
 
-def test_mixed_on_mesh_decodes_and_bounds_compiles(mixed_ds, monkeypatch):
+def test_mixed_on_mesh_decodes_and_bounds_compiles(mixed_ds):
     """'device-mixed' works across a mesh.  The decode is
-    host-local (geometry buckets may differ per host), delivery scatters the
-    decoded rows over the mesh as a global array, and the compile count stays
-    bounded by the stamped dataset-level geometry contract."""
+    host-local (canvas groups may differ per host), delivery scatters the
+    decoded rows and their extents over the mesh as global arrays, and the
+    compile count stays at one program per canvas and chroma layout."""
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-
-    import petastorm_tpu.ops.jpeg as ops_jpeg
-
-    signatures = set()
-    real = ops_jpeg.decode_coefficients
-
-    def recording(planes, qtabs, image_size, sampling, **kw):
-        signatures.add((tuple(p.shape for p in planes), image_size, sampling))
-        return real(planes, qtabs, image_size=image_size, sampling=sampling, **kw)
-
-    monkeypatch.setattr(ops_jpeg, "decode_coefficients", recording)
 
     mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
     with make_batch_reader(mixed_ds, shuffle_row_groups=False, num_epochs=1,
@@ -190,12 +176,14 @@ def test_mixed_on_mesh_decodes_and_bounds_compiles(mixed_ds, monkeypatch):
                 assert b["image"].shape == (8,) + TARGET
                 assert b["image"].sharding.spec == P("data")
                 assert len(b["image"].sharding.device_set) == 8
+                assert b["image_extent"].shape == (8, 2)
+                assert b["image_extent"].sharding.spec == P("data")
                 imgs = np.asarray(b["image"])
                 for k, i in enumerate(np.asarray(b["idx"])):
                     got[int(i)] = imgs[k]
             diag = loader.diagnostics
     assert sorted(got) == list(range(N_ROWS))
-    assert len(signatures) == len(GEOMETRIES)  # bounded compiles on the mesh
+    assert diag["mixed_finish_programs"] == 1  # bounded compiles on the mesh
     assert diag["mixed_decode_geometries"] == {"image": len(GEOMETRIES)}
     assert diag["declared_geometries"] == {"image": len(GEOMETRIES)}
     for i in range(N_ROWS):

@@ -118,3 +118,43 @@ def test_random_resized_crop_and_flip_batch(one_chip):
     compiled = jax.jit(augment).lower(images, key).compile()
     out = compiled.out_info
     assert (out.shape, out.dtype) == ((32, SIDE, SIDE, 3), jnp.uint8)
+
+
+def test_jpeg_finish_canvas_420_group(one_chip):
+    """The 'device-mixed' finish of one group of 32 images in the largest
+    ILSVRC canvas (640x640, 4:2:0), written into a 256-row batch."""
+    from petastorm_tpu.ops.jpeg import finish_canvas
+
+    n, blocks = 32, 640 // 8
+    sds = jax.ShapeDtypeStruct
+    planes = (sds((n, blocks, blocks, 64), jnp.int16, sharding=one_chip),
+              sds((n, blocks // 2, blocks // 2, 64), jnp.int16,
+                  sharding=one_chip),
+              sds((n, blocks // 2, blocks // 2, 64), jnp.int16,
+                  sharding=one_chip))
+    lowered = finish_canvas.lower(
+        sds((256, 640, 640, 3), jnp.uint8, sharding=one_chip), planes,
+        sds((n, 3, 64), jnp.uint16, sharding=one_chip),
+        sds((n, 2), jnp.int32, sharding=one_chip),
+        sds((n,), jnp.int32, sharding=one_chip),
+        canvas_hw=(640, 640), sampling=((2, 2), (1, 1), (1, 1)))
+    assert "HIGHEST" in lowered.as_text()
+    compiled = lowered.compile()
+    out = compiled.out_info
+    assert (out.shape, out.dtype) == ((256, 640, 640, 3), jnp.uint8)
+    # the batch is donated and written in place: no second copy of it
+    assert compiled.memory_analysis().alias_size_in_bytes == 256 * 640 * 640 * 3
+
+
+def test_random_resized_crop_in_extents_batch(one_chip):
+    from petastorm_tpu.ops import random_resized_crop
+
+    images = jax.ShapeDtypeStruct((256, 640, 640, 3), jnp.uint8,
+                                  sharding=one_chip)
+    extents = jax.ShapeDtypeStruct((256, 2), jnp.int32, sharding=one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda x, k, e: random_resized_crop(x, k, (SIDE, SIDE), extents=e)
+    ).lower(images, key, extents).compile()
+    out = compiled.out_info
+    assert (out.shape, out.dtype) == ((256, SIDE, SIDE, 3), jnp.uint8)
