@@ -203,12 +203,15 @@ def finish_canvas(out: jax.Array, planes: Sequence[jax.Array],
       extents: int32 ``(G, 2)``: each image's own (height, width).
       rows: int32 ``(G,)``: the row of ``out`` each image goes to; a row
         outside ``out`` (the group's padding) is dropped.
-      canvas_hw: (height, width) of the canvas region written.
+      canvas_hw: (height, width) of the canvas the planes hold.
       sampling: per component (h_samp, v_samp).
 
-    Inside each image's extent the pixels are those ``decode_coefficients``
-    gives for the image's own geometry: fancy upsampling replicates at the
-    image's own right and bottom edge.  Outside it they are 0.
+    Each image's row of ``out`` is written whole, in place and with no
+    loop.  Inside the image's extent the pixels are those
+    ``decode_coefficients`` gives for the image's own geometry: fancy
+    upsampling replicates at the image's own right and bottom edge.
+    Everywhere else, up to the batch's ``(H, W)``, they are 0.  Rows of
+    ``out`` that no image goes to are left as they are.
     """
     ncomp = len(planes)
     if ncomp not in (1, 3):
@@ -246,7 +249,33 @@ def finish_canvas(out: jax.Array, planes: Sequence[jax.Array],
     img = jnp.where(inside, img, jnp.uint8(0))
     if out.ndim == 4 and img.ndim == 3:
         img = jnp.repeat(img[..., None], out.shape[-1], axis=-1)
-    return out.at[rows, :ch, :cw].set(img, mode="drop")
+    # In-place slices offset in the row index alone: a scatter of part of
+    # each row compiles to a loop that reads each image's window before it
+    # writes it.  A padding slot rewrites the first member's row with the
+    # first member's image.
+    oh, ow = out.shape[1:3]
+    rest = (0,) * (out.ndim - 3)
+    valid = (rows >= 0) & (rows < out.shape[0])
+    first = jnp.argmax(valid)
+    src = jnp.where(valid, jnp.arange(len(rows)), first)
+    dst = jnp.where(valid, rows, rows[first])
+
+    def write(out):
+        for i in range(len(rows)):
+            out = jax.lax.dynamic_update_slice(
+                out, jax.lax.dynamic_index_in_dim(img, src[i]),
+                (dst[i], 0, 0) + rest)
+            if ch < oh:
+                out = jax.lax.dynamic_update_slice(out, jnp.zeros(
+                    (1, oh - ch, ow) + out.shape[3:], jnp.uint8),
+                    (dst[i], ch, 0) + rest)
+            if cw < ow:
+                out = jax.lax.dynamic_update_slice(out, jnp.zeros(
+                    (1, ch, ow - cw) + out.shape[3:], jnp.uint8),
+                    (dst[i], 0, cw) + rest)
+        return out
+
+    return jax.lax.cond(valid.any(), write, lambda out: out, out)
 
 
 def decode_from_layout(planes, qtabs, layout, out_dtype=jnp.uint8,

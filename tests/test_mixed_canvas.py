@@ -124,6 +124,55 @@ def test_canvas_finish_equals_per_geometry_finish(chroma):
         assert not out[i, h:].any() and not out[i, :, w:].any(), (h, w)
 
 
+def test_canvas_finish_writes_member_rows_whole_and_nothing_else():
+    """Into a batch of a nonzero sentinel, two groups - one in a canvas
+    smaller than the batch's rows, one in the delivered canvas - each with
+    padding slots that hold a real image: every member row is its own
+    finish inside its extent and 0 in the rest of its row, rows of no group
+    keep the sentinel, and no padding slot writes anywhere, nor a group of
+    padding alone."""
+    from petastorm_tpu.jax.loader import _CanvasGroup
+
+    geoms = [(37, 50), (48, 61), (21, 64), (60, 77), (53, 33)]
+    bufs = [_encode(_textured(h, w, 30 + i), quality=90)
+            for i, (h, w) in enumerate(geoms)]
+    cells = []
+    for buf in bufs:
+        planes, qtab, _ = native_image.read_jpeg_coefficients(buf)
+        cells.append((tuple(planes), qtab, None))
+    sampling = tuple((h, v) for (h, v, _, _)
+                     in native_image.jpeg_coef_layout(bufs[0]).components)
+    extents = np.asarray(geoms, np.int32)
+    n_out, rows_of = 8, {0: 5, 1: 0, 2: 3, 3: 1, 4: 4}
+    out = jnp.full((n_out, 64, 80, 3), 77, jnp.uint8)
+    for canvas, members, size in (((48, 64), [0, 1, 2], 4),
+                                  ((64, 80), [3, 4], 4)):
+        g = _CanvasGroup.pack(cells, members, extents, canvas, sampling, size)
+        # padding slots that hold the last member's image, not the first's
+        pad, last = g.rows < 0, len(members) - 1
+        for p in g.planes:
+            p[pad] = p[last]
+        g.qtabs[pad], g.extents[pad] = g.qtabs[last], g.extents[last]
+        rows = np.asarray([rows_of[int(r)] if r >= 0 else n_out
+                           for r in g.rows], np.int32)
+        out = finish_canvas(out, tuple(jnp.asarray(p) for p in g.planes),
+                            jnp.asarray(g.qtabs), jnp.asarray(g.extents),
+                            jnp.asarray(rows), canvas_hw=canvas,
+                            sampling=sampling)
+    # a group of padding alone writes nothing
+    out = finish_canvas(out, tuple(jnp.asarray(p) for p in g.planes),
+                        jnp.asarray(g.qtabs), jnp.asarray(g.extents),
+                        jnp.full(len(g.rows), n_out, jnp.int32),
+                        canvas_hw=canvas, sampling=sampling)
+    out = np.asarray(out)
+    for i, (h, w) in enumerate(geoms):
+        row = out[rows_of[i]]
+        _assert_equal_inside(row[:h, :w], _own_finish(bufs[i]), (h, w))
+        assert not row[h:].any() and not row[:, w:].any(), (h, w)
+    for r in sorted(set(range(n_out)) - set(rows_of.values())):
+        assert (out[r] == 77).all(), r
+
+
 @pytest.fixture(scope="module")
 def canvas_ds(tmp_path_factory):
     """40 rows over 20 distinct geometries up to 64x64, 4:2:0."""

@@ -120,29 +120,44 @@ def test_random_resized_crop_and_flip_batch(one_chip):
     assert (out.shape, out.dtype) == ((32, SIDE, SIDE, 3), jnp.uint8)
 
 
-def test_jpeg_finish_canvas_420_group(one_chip):
-    """The 'device-mixed' finish of one group of 32 images in the largest
-    ILSVRC canvas (640x640, 4:2:0), written into a 256-row batch."""
+def _finish_canvas_lowered(one_chip, canvas, n):
+    """The 'device-mixed' finish of one 4:2:0 group of ``n`` images in an
+    ILSVRC ``canvas``, written into a 256-row 640x640 batch."""
     from petastorm_tpu.ops.jpeg import finish_canvas
 
-    n, blocks = 32, 640 // 8
-    sds = jax.ShapeDtypeStruct
-    planes = (sds((n, blocks, blocks, 64), jnp.int16, sharding=one_chip),
-              sds((n, blocks // 2, blocks // 2, 64), jnp.int16,
-                  sharding=one_chip),
-              sds((n, blocks // 2, blocks // 2, 64), jnp.int16,
-                  sharding=one_chip))
-    lowered = finish_canvas.lower(
+    (ch, cw), sds = canvas, jax.ShapeDtypeStruct
+    planes = (sds((n, ch // 8, cw // 8, 64), jnp.int16, sharding=one_chip),
+              sds((n, ch // 16, cw // 16, 64), jnp.int16, sharding=one_chip),
+              sds((n, ch // 16, cw // 16, 64), jnp.int16, sharding=one_chip))
+    return finish_canvas.lower(
         sds((256, 640, 640, 3), jnp.uint8, sharding=one_chip), planes,
         sds((n, 3, 64), jnp.uint16, sharding=one_chip),
         sds((n, 2), jnp.int32, sharding=one_chip),
         sds((n,), jnp.int32, sharding=one_chip),
-        canvas_hw=(640, 640), sampling=((2, 2), (1, 1), (1, 1)))
+        canvas_hw=canvas, sampling=((2, 2), (1, 1), (1, 1)))
+
+
+def test_jpeg_finish_canvas_420_group(one_chip):
+    """The 'device-mixed' finish of one group of 32 images in the largest
+    ILSVRC canvas (640x640, 4:2:0), written into a 256-row batch."""
+    lowered = _finish_canvas_lowered(one_chip, (640, 640), 32)
     assert "HIGHEST" in lowered.as_text()
     compiled = lowered.compile()
     out = compiled.out_info
     assert (out.shape, out.dtype) == ((256, 640, 640, 3), jnp.uint8)
     # the batch is donated and written in place: no second copy of it
+    assert compiled.memory_analysis().alias_size_in_bytes == 256 * 640 * 640 * 3
+
+
+@pytest.mark.parametrize("canvas,n", [((384, 512), 32), ((512, 384), 32),
+                                      ((512, 512), 32), ((640, 640), 32),
+                                      ((384, 512), 8)])
+def test_jpeg_finish_canvas_writes_whole_rows(one_chip, canvas, n):
+    """Every ILSVRC canvas writes its group's rows of the batch whole, in
+    place and with no loop: a scatter of part of each row compiles to a
+    loop of one read-select-write per image."""
+    compiled = _finish_canvas_lowered(one_chip, canvas, n).compile()
+    assert "while" not in compiled.as_text()
     assert compiled.memory_analysis().alias_size_in_bytes == 256 * 640 * 640 * 3
 
 
