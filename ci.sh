@@ -930,122 +930,6 @@ PY
 JAX_PLATFORMS=cpu PYTHONPATH="$PWD" timeout -k 10 200 python "$HA_SMOKE"
 rm -f "$HA_SMOKE"
 
-echo "== service colocated shm ratio (REQUIRE_ARENA runtimes: 0.9x floor armed) =="
-# the owed ISSUE 12 capture: on the py3.12 REQUIRE_ARENA job the shm arena
-# plane MUST be live, so the co-located descriptor-only fast path is
-# measured for real (same-session interleaved A/B vs the in-process pool,
-# bench.py bench_service shape) and gated against the 0.9x absolute floor
-# in tools/bench_compare.py ABSOLUTE_FLOORS.  Elsewhere the plane is
-# legitimately dark and the capture skips - the bench owns the number.
-if [ "${PETASTORM_TPU_REQUIRE_ARENA:-0}" = "1" ]; then
-    RATIO_OUT="$(mktemp /tmp/petastorm_tpu_svc_ratio_XXXXXX.json)"
-    RATIO_SMOKE="$(mktemp /tmp/petastorm_tpu_svc_ratio_XXXXXX.py)"
-    cat > "$RATIO_SMOKE" <<'PY'
-import json
-import re
-import statistics
-import subprocess
-import sys
-import tempfile
-import time
-
-import numpy as np
-
-from petastorm_tpu.codecs import CompressedImageCodec, ScalarCodec
-from petastorm_tpu.etl.writer import write_dataset
-from petastorm_tpu.reader import make_batch_reader
-from petastorm_tpu.schema import Field, Schema
-from petastorm_tpu.service.protocol import (connect_frames, parse_address,
-                                            shm_transport_available)
-from petastorm_tpu.test_util.synthetic import synthetic_rgb_image
-
-# the REQUIRE_ARENA contract: a dark arena plane on this job is a CI
-# failure, not a skip (the exact mode that hid a broken .so for a PR cycle)
-assert shm_transport_available(), \
-    "REQUIRE_ARENA=1 but the shm transport plane is dark"
-
-out_path = sys.argv[1]
-tmp = tempfile.mkdtemp(prefix="petastorm_tpu_svc_ratio_")
-url = f"{tmp}/img"
-schema = Schema("Img", [
-    Field("label", np.int64, (), ScalarCodec()),
-    Field("image", np.uint8, (224, 224, 3),
-          CompressedImageCodec("jpeg", quality=90)),
-])
-write_dataset(url, schema,
-              [{"label": i, "image": synthetic_rgb_image(i, 224, 224)}
-               for i in range(128)], row_group_size_rows=32)
-
-def one_read(**kwargs):
-    t0 = time.perf_counter()
-    with make_batch_reader(url, shuffle_row_groups=False, num_epochs=2,
-                           **kwargs) as r:
-        rows = sum(b.num_rows for b in r.iter_batches())
-    assert rows == 256, rows
-    return rows / (time.perf_counter() - t0)
-
-def stats(addr):
-    conn = connect_frames(parse_address(addr), timeout=5.0)
-    try:
-        conn.send({"t": "stats?"})
-        return conn.recv(timeout=5.0)["stats"]
-    finally:
-        conn.close()
-
-procs = []
-try:
-    disp = subprocess.Popen(
-        [sys.executable, "-m", "petastorm_tpu.service.cli", "dispatcher",
-         "--host", "127.0.0.1", "--port", "0"],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True)
-    procs.append(disp)
-    addr = re.search(r"listening on (\S+)",
-                     disp.stdout.readline()).group(1)
-    procs.extend(subprocess.Popen(
-        [sys.executable, "-m", "petastorm_tpu.service.cli", "worker",
-         "--address", addr, "--capacity", "1", "--name", f"shm{i}",
-         "--shm-size-mb", "512"],
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
-        for i in range(2))
-    deadline = time.monotonic() + 30
-    while len(stats(addr)["workers"]) < 2:
-        assert time.monotonic() < deadline, "fleet never registered"
-        time.sleep(0.1)
-    one_read(service_address=addr)                       # warmup
-    one_read(reader_pool_type="thread", workers_count=2)
-    colo, anchor = [], []
-    for _ in range(3):  # interleaved A/B pairs: drift-immune same-session
-        anchor.append(one_read(reader_pool_type="thread", workers_count=2))
-        colo.append(one_read(service_address=addr))
-    counters = stats(addr)["counters"]
-finally:
-    for p in procs:
-        if p.poll() is None:
-            p.kill()
-shm_frames = int(counters.get("service.frames_shm", 0))
-assert shm_frames >= 1, \
-    f"co-located fast path never engaged: {counters}"
-ratio = statistics.median(colo) / statistics.median(anchor)
-with open(out_path, "w") as f:
-    f.write(json.dumps({"metric": "service_colocated_vs_inprocess_ratio",
-                        "value": ratio, "unit": "x"}) + "\n")
-print(f"service_colocated_vs_inprocess_ratio {ratio:.3f}x"
-      f" ({shm_frames} shm frames; colo {statistics.median(colo):.1f}"
-      f" vs in-process {statistics.median(anchor):.1f} samples/sec)")
-PY
-    JAX_PLATFORMS=cpu PYTHONPATH="$PWD" timeout -k 10 300 \
-        python "$RATIO_SMOKE" "$RATIO_OUT"
-    # same file on both sides: deltas are zero, so the gate reduces to the
-    # ABSOLUTE_FLOORS entry - exactly the 0.9x acceptance bar, armed
-    PYTHONPATH="$PWD" python tools/bench_compare.py \
-        "$RATIO_OUT" "$RATIO_OUT" \
-        --metrics service_colocated_vs_inprocess_ratio --fail-threshold 0
-    rm -f "$RATIO_SMOKE" "$RATIO_OUT"
-else
-    echo "skipped: arena plane not required on this runtime (the py3.12" \
-         "REQUIRE_ARENA job captures and gates the colocated ratio)"
-fi
-
 echo "== autoscale smoke (closed loop: starved client forces a scale-up, idle drain a graceful retire) =="
 # the full ISSUE 14 loop as real CLI processes under timeout: a dispatcher,
 # a `petastorm-tpu-service autoscale` supervisor (floor 1 / ceiling 2), and

@@ -89,7 +89,7 @@ class TfdataDeviceFeed:
 
     Mirrors JaxDataLoader's consumer contract: ``next()`` yields a dict of
     ready device arrays and ``consumer_wait_s`` accumulates the time the
-    consumer spent blocked - the input-attributable device idle.
+    consumer spent blocked waiting for input.
     """
 
     def __init__(self, tfr_path: str, global_batch: int, prefetch: int,
@@ -257,8 +257,8 @@ def train(dataset_url: str, steps: int, global_batch: int, side: int,
           workers: int = 4, prefetch: int = 2, cache: str = "null",
           input_pipeline: str = "petastorm", scan_steps: int = 1) -> dict:
     """Run ``steps`` real ResNet-50 train steps fed by the loader; returns a
-    metrics dict incl. samples/sec/chip and the input-attributable device-idle
-    percentage (consumer wait vs wall time over the measured window)."""
+    metrics dict incl. samples/sec/chip and ``input_wait_pct`` (consumer wait
+    over wall time in the measured window)."""
     devices = jax.devices()
     mesh = Mesh(np.asarray(devices), ("data",))
     params, opt_state, train_step, train_scan = make_train_step(
@@ -301,46 +301,6 @@ def train(dataset_url: str, steps: int, global_batch: int, side: int,
             return {"image": jnp.stack([b["image"] for b in bs]),
                     "label": jnp.stack([b["label"] for b in bs])}
 
-        def measure_peak_flops():
-            """Same-session matmul peak probe for the MFU denominator.
-            Chained bf16 4096x4096 matmuls in ONE dispatch (lax.fori_loop),
-            timed at TWO iteration counts and differenced - the marginal
-            slope cancels the fixed dispatch+fetch cost.  Every timing here
-            ends in a value fetch, which waits for the device.  FLOPs
-            counted as 2*n^3 per matmul - the same FMA=2 convention as XLA's
-            cost_analysis numerator."""
-            if jax.default_backend() == "cpu":
-                return None  # minutes on CPU, and MFU is a chip metric
-            n = 4096
-            a = jax.random.normal(jax.random.PRNGKey(0), (n, n),
-                                  jnp.bfloat16) * 0.01
-            b = jax.random.normal(jax.random.PRNGKey(1), (n, n),
-                                  jnp.bfloat16) * 0.01
-
-            def make_burn(iters):
-                @jax.jit
-                def burn(a, b):
-                    out = jax.lax.fori_loop(0, iters, lambda i, c: c @ b, a)
-                    return (out.astype(jnp.float32) ** 2).sum()
-                return burn
-
-            lo, hi = 128, 512
-            burns = {it: make_burn(it) for it in (lo, hi)}
-            for it in (lo, hi):
-                float(burns[it](a, b))  # compile + settle
-            # INTERLEAVED passes, min per size: alternating the two sizes
-            # puts both under the same fixed overheads
-            best = {lo: float("inf"), hi: float("inf")}
-            for _ in range(3):
-                for it in (lo, hi):
-                    t0 = time.perf_counter()
-                    float(burns[it](a, b))  # the fetch IS the sync
-                    best[it] = min(best[it], time.perf_counter() - t0)
-            slope = (best[hi] - best[lo]) / (hi - lo)
-            if slope <= 0:
-                return None  # drift swamped the probe; fall back to nominal
-            return 2 * n ** 3 / slope
-
         # AOT-compile the step once: the SAME executable runs the loop AND
         # reports XLA's FLOP estimate for the whole dispatch - the MFU
         # numerator comes from the compiler, not a hand-derived constant
@@ -363,8 +323,8 @@ def train(dataset_url: str, steps: int, global_batch: int, side: int,
         params, opt_state, loss = run_unit(params, opt_state, unit0, aug_key)
         float(loss)
         # consumer wait accumulates while the consumer blocks on the prefetch
-        # queue: the delta over the measured window IS the device-idle time
-        # attributable to input starvation during REAL train steps
+        # queue: the delta over the measured window is the time the train
+        # loop spent waiting on input during REAL train steps
         wait0 = consumer_wait(feed)
         n_disp = 0
         t0 = time.perf_counter()
@@ -388,26 +348,23 @@ def train(dataset_url: str, steps: int, global_batch: int, side: int,
                                      jax.random.fold_in(aug_key, 1 << 20 | i))
         float(loss2)
         compute_dt = time.perf_counter() - t1
-        # the probe runs LAST, so its matmuls cannot disturb the windows
-        measured_peak = measure_peak_flops()
         diag = feed.diagnostics if hasattr(feed, "diagnostics") else {}
     samples = step * global_batch
     # per-sample FLOPs only from the SINGLE-step lowering: XLA's cost model
     # counts a lax.scan body ONCE (verified: scan=8 reports exactly 1/8th),
     # so the scan executable's figure is not per-sample-meaningful - callers
     # wanting scan-mode MFU should take flops_per_sample from a scan=1 run
-    # of the same shapes (bench.py does exactly that)
+    # of the same shapes
     flops_per_sample = (flops_per_dispatch / global_batch
                         if flops_per_dispatch and scan_steps <= 1 else None)
     return {
         "flops_per_dispatch": flops_per_dispatch,
         "samples_per_sec": samples / dt,
         "samples_per_sec_per_chip": samples / dt / len(devices),
-        "device_idle_pct": 100.0 * input_wait_s / dt,
+        "input_wait_pct": 100.0 * input_wait_s / dt,
         "input_stall_pct": 100.0 * max(0.0, dt - compute_dt) / dt,
         "compute_floor_wall_s": compute_dt,
         "flops_per_sample": flops_per_sample,
-        "measured_peak_flops": measured_peak,
         "device_kind": devices[0].device_kind,
         "steps": step,
         "scan_steps": scan_steps,
@@ -467,6 +424,6 @@ if __name__ == "__main__":
         print(f"{m['steps'] * m['global_batch']} samples in {m['wall_s']:.2f}s"
               f" = {m['samples_per_sec']:.1f} samples/sec"
               f" ({m['samples_per_sec_per_chip']:.1f} samples/sec/chip on"
-              f" {m['n_devices']} chip(s)), device idle"
-              f" {m['device_idle_pct']:.1f}% (input-bound), final loss"
+              f" {m['n_devices']} chip(s)), input wait"
+              f" {m['input_wait_pct']:.1f}% of wall time, final loss"
               f" {m['final_loss']:.4f}")

@@ -1,7 +1,7 @@
 """Single-span rowgroup prefetch: kill remote read amplification.
 
-BENCH_r05 measured the pre_buffer path at ~1.7 ranged reads per rowgroup for
-an 8-column dataset - arrow's lazy cache coalesces *adjacent* column chunks
+The pre_buffer path can take more than one ranged read per rowgroup on a
+many-column dataset - arrow's lazy cache coalesces *adjacent* column chunks
 but still splits a rowgroup across reads when chunk gaps exceed its hole
 limit, and every read pays the object store's per-request latency.  This
 module sizes the window ITSELF: a rowgroup's needed column chunks occupy one

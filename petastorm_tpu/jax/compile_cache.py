@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache for the repository's entry points.
 
 The library sets nothing when it is imported: a program that compiles on
-the chip (``chip_smoke.py``, ``bench.py``, the ImageNet example) calls
+the chip (``chip_smoke.py``, the ImageNet example) calls
 :func:`enable_compile_cache` once, before its first compile.  The cache
 lives where ``JAX_COMPILATION_CACHE_DIR`` says when it is set, and otherwise
 at one fixed path inside the checkout - never a temp, pid or time name, since

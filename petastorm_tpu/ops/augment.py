@@ -257,12 +257,11 @@ def random_resized_crop(images: jax.Array, key: jax.Array,
 
     ``antialias`` defaults OFF - plain bilinear sampling is the classic
     ImageNet-training behavior (torchvision pre-v2).  For this op's
-    per-image traced scales the antialiased form measures near-parity on a
-    v5e chip (0.7 vs 0.4 ms per 256-image batch; see
-    benchmark/ops_microbench.py), so turning it on for torchvision-v2
-    quality parity is fine.  Beware hand-rolled variants whose crop scale
-    constant-folds at trace time: one such configuration measured 149 ms for
-    the same batch - keep the scale a traced value if you fork this.
+    per-image traced scales the antialiased form costs about as much as the
+    plain one, so turning it on for torchvision-v2 quality parity is fine.
+    Beware hand-rolled variants whose crop scale constant-folds at trace
+    time: they compile to a far slower kernel for the same batch - keep the
+    scale a traced value if you fork this.
     """
     if extents is not None:
         if method != "bilinear" or antialias:

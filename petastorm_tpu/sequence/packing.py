@@ -21,7 +21,7 @@ Two delivery modes:
 
 * **packed** (:class:`SequencePacker` / :func:`iter_packed_blocks`): dense
   ``(batch, seq_len)`` blocks; fill-rate typically >= 0.85 on real-corpus
-  length distributions (gated in tools/bench_compare.py).
+  length distributions.
 * **ragged** (:func:`iter_ragged_batches`): flat token buffer + offsets for
   consumers that pack on-device (e.g. a jit-compiled packer kernel).
 

@@ -125,8 +125,8 @@ def write_token_corpus(url: str, n_docs: int = 400,
     a ``tokens`` variable-length int32 column (lognormal document lengths -
     the long-tail shape real corpora have), and an optional small-cardinality
     ``label_field`` for predicate tests.  Shared by the chaos-matrix token
-    cells, the ci.sh sequence smoke and ``bench.py bench_sequence_packing``
-    so all three measure the same corpus shape.  Returns total tokens."""
+    cells and the ci.sh sequence smoke so both see the same corpus shape.
+    Returns total tokens."""
     import numpy as np
 
     from petastorm_tpu.sequence.dataset import token_field
@@ -162,8 +162,7 @@ def write_wide_dataset(url: str, n_cols: int = 8, n_rowgroups: int = 8,
                        seed: int = 0) -> None:
     """A many-column 'wide' parquet dataset: an ``id`` int64 column plus
     ``n_cols - 1`` float32 vector columns - the shape where per-column-chunk
-    remote reads would hurt most.  Shared by the remote-latency tests and
-    ``bench.py``'s latent-vs-local config so both measure the same dataset."""
+    remote reads would hurt most.  Used by the remote-read tests."""
     schema = Schema("Wide", [Field("id", np.int64)] + [
         Field(f"c{i}", np.float32, (vec_len,)) for i in range(n_cols - 1)])
     rng = np.random.default_rng(seed)

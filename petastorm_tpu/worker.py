@@ -204,8 +204,8 @@ class RowGroupDecoderWorker:
                 else:
                     # remote stores: wrap the file in a WindowedFile so each
                     # rowgroup's column span is fetched in ONE ranged read
-                    # (io_window; kills the ~1.7 reads/rowgroup amplification
-                    # BENCH_r05 measured) with raw reads counted for the
+                    # (io_window; kills the multi-read amplification of
+                    # arrow's pre_buffer) with raw reads counted for the
                     # io.reads_per_rowgroup telemetry.  pre_buffer stays on
                     # as the fallback coalescer for spans the window guard
                     # rejects - its ranged reads land inside the window when

@@ -52,7 +52,7 @@ def test_imagenet_resnet_smoke(tmp_path):
     m = train(url, steps=2, global_batch=8, side=64, num_classes=10,
               decode="host")
     assert m["samples_per_sec"] > 0
-    assert 0.0 <= m["device_idle_pct"] <= 100.0
+    assert 0.0 <= m["input_wait_pct"] <= 100.0
     assert m["diagnostics"]["delivered_batches"] >= m["steps"]
     # hybrid on-chip decode (the default) feeds the same training step
     m = train(url, steps=2, global_batch=8, side=64, num_classes=10,

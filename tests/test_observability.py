@@ -1,5 +1,5 @@
 """Live observability layer: metrics sampler, flight recorder, Prometheus
-export endpoint, JSONL sink, ``diagnose --watch``, and bench_compare.
+export endpoint, JSONL sink and ``diagnose --watch``.
 
 The e2e acceptance test mirrors the PR gate: a chaos-induced permanent hang
 with no ``item_deadline_s`` must abort with ``PipelineStallError`` AND leave
@@ -419,66 +419,6 @@ def test_diagnose_metrics_port_flag(dataset, capsys):
     assert result["metrics_port"]
 
 
-# -- bench_compare ------------------------------------------------------------
-
-def _write_bench(path, metrics):
-    lines = [json.dumps({"metric": k, "value": v, "unit": "x"})
-             for k, v in metrics.items()]
-    path.write_text("\n".join(lines) + "\n")
-
-
-def test_bench_compare_report_and_gate(tmp_path, capsys):
-    from tools import bench_compare
-
-    old, new = tmp_path / "old.json", tmp_path / "new.json"
-    _write_bench(old, {"hello_world_samples_per_sec": 1000.0,
-                       "train_device_idle_pct": 10.0})
-    _write_bench(new, {"hello_world_samples_per_sec": 950.0,
-                       "train_device_idle_pct": 9.0})
-    # report-only: 5% throughput drop + idle improvement, no gate -> 0
-    assert bench_compare.main([str(old), str(new)]) == 0
-    # gate at 10%: nothing worse than 10% -> still 0
-    assert bench_compare.main([str(old), str(new),
-                               "--fail-threshold", "10"]) == 0
-    # gate at 3%: the 5% throughput drop regresses -> 1, named in output
-    capsys.readouterr()
-    assert bench_compare.main([str(old), str(new),
-                               "--fail-threshold", "3"]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "hello_world_samples_per_sec" in out
-
-
-def test_bench_compare_lower_is_better_direction(tmp_path):
-    from tools import bench_compare
-
-    old, new = tmp_path / "old.json", tmp_path / "new.json"
-    _write_bench(old, {"train_device_idle_pct": 10.0})
-    _write_bench(new, {"train_device_idle_pct": 20.0})  # idle DOUBLED: worse
-    assert bench_compare.main([str(old), str(new),
-                               "--fail-threshold", "50"]) == 1
-
-
-def test_bench_compare_missing_candidate_metric_fails_gate(tmp_path, capsys):
-    # a metric the candidate stopped emitting (bench crashed mid-run) is the
-    # worst regression, not a silent pass
-    from tools import bench_compare
-
-    old, new = tmp_path / "old.json", tmp_path / "new.json"
-    _write_bench(old, {"mnist_rows_per_sec": 1000.0,
-                       "ngram_windows_per_sec": 500.0})
-    _write_bench(new, {"mnist_rows_per_sec": 1000.0})
-    assert bench_compare.main([str(old), str(new)]) == 0  # report-only
-    assert bench_compare.main([str(old), str(new),
-                               "--fail-threshold", "99"]) == 1
-    capsys.readouterr()
-    # a NEW metric missing from the baseline is not a regression
-    _write_bench(new, {"mnist_rows_per_sec": 1000.0,
-                       "ngram_windows_per_sec": 500.0,
-                       "brand_new_metric": 7.0})
-    assert bench_compare.main([str(old), str(new),
-                               "--fail-threshold", "99"]) == 0
-
-
 def test_reader_warns_when_flight_record_requested_but_sampling_off(
         dataset, tmp_path, caplog):
     import logging
@@ -491,20 +431,3 @@ def test_reader_warns_when_flight_record_requested_but_sampling_off(
             assert reader.sampler is None
             next(reader.iter_batches())
     assert any("inert" in r.message for r in caplog.records)
-
-
-def test_bench_compare_parses_driver_capture_and_summary(tmp_path):
-    from tools import bench_compare
-
-    tail = "\n".join([
-        "some non-json noise",
-        json.dumps({"metric": "bench_summary",
-                    "metrics": {"mnist_rows_per_sec": [500000.0, 1.1]}}),
-        json.dumps({"metric": "hello_world_samples_per_sec",
-                    "value": 2900.0, "unit": "samples/sec"}),
-    ])
-    cap = tmp_path / "BENCH_rX.json"
-    cap.write_text(json.dumps({"n": 5, "rc": 0, "tail": tail}))
-    metrics = bench_compare.load_metrics(str(cap))
-    assert metrics == {"mnist_rows_per_sec": 500000.0,
-                       "hello_world_samples_per_sec": 2900.0}

@@ -35,8 +35,7 @@ ROWS_PER_RG = 32
 
 @pytest.fixture(scope="module")
 def wide_ds(tmp_path_factory):
-    """Many-column dataset: the shape where per-column reads would hurt
-    (shared builder with bench.py's latent-vs-local config)."""
+    """Many-column dataset: the shape where per-column reads would hurt."""
     url = str(tmp_path_factory.mktemp("latent") / "wide")
     write_wide_dataset(url, n_cols=N_COLS, n_rowgroups=N_ROWGROUPS,
                        rows_per_rg=ROWS_PER_RG)
